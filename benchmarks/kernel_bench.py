@@ -214,7 +214,7 @@ def _wire_rows(add, alpha: float):
 
         sizes = tuple(l.size for l in leaves)
         d = sum(sizes)
-        cap = wire.mask_value_capacity(sizes, alpha)
+        cap = wire.mask_leaf_capacities(sizes, alpha)
 
         dense_fn = jax.jit(lambda a, b, c: wire.pack_dense((a, b, c)))
         t_dense = _time(dense_fn, sW, sM, sV)

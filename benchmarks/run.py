@@ -33,6 +33,8 @@ def main() -> None:
     unknown = want - set(SUITES)
     if unknown:
         ap.error(f"unknown suite(s) {sorted(unknown)}; known: {SUITES}")
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
 

@@ -38,17 +38,9 @@ from jax.sharding import PartitionSpec
 
 from repro.core import sparsify as S
 from repro.kernels.topk_mask.ops import overselect_bound
+from repro.sharding import hint
 
 _F32 = jnp.float32
-
-
-def _maybe_replicate(x):
-    """Force replication across the mesh (the all-gather) when tracing
-    under a mesh; no-op in plain CPU tests."""
-    try:
-        return lax.with_sharding_constraint(x, PartitionSpec())
-    except Exception:
-        return x
 
 
 def dense_weighted_sum(tree_c, weights):
@@ -165,9 +157,9 @@ def sparse_shared_gather_sum(sW_c, sM_c, sV_c, alpha, weights,
             dt = jnp.dtype(value_dtype)
             vw, vm, vv = (t.astype(dt) for t in (vw, vm, vv))
         # the uplink: replicate the packed representation (all-gather)
-        idx = _maybe_replicate(idx)
-        valid = _maybe_replicate(valid)
-        vw, vm, vv = map(_maybe_replicate, (vw, vm, vv))
+        idx = hint(idx)
+        valid = hint(valid)
+        vw, vm, vv = map(hint, (vw, vm, vv))
         shape = w_c.shape[1:]
         return (
             _scatter_weighted(vw, idx, valid, weights, n).reshape(shape),
@@ -273,8 +265,6 @@ def make_shardmap_sparse_aggregate(mesh, param_pspecs, client_axes, alpha,
     drop obeys the same error-feedback semantics as mask drop instead of
     silently vanishing.  When nothing overflows the residual is returned
     bit-unchanged."""
-    from repro.compat import shard_map
-
     caxes = tuple(client_axes)
     cax_entry = caxes if len(caxes) > 1 else caxes[0]
 
@@ -357,7 +347,7 @@ def make_shardmap_sparse_aggregate(mesh, param_pspecs, client_axes, alpha,
     def agg(sW_c, sM_c, sV_c, weights, comp_err=None):
         has_err = comp_err is not None
         err_spec = stacked_spec if has_err else None
-        aW, aM, aV, new_err = shard_map(
+        aW, aM, aV, new_err = jax.shard_map(
             body, mesh=mesh,
             in_specs=(stacked_spec, stacked_spec, stacked_spec, wspec,
                       err_spec),
@@ -387,7 +377,7 @@ def wire_gather_sum(compressor, payload_c, like, weights):
     and fold in client order with ``round_scan``'s exact arithmetic, so
     the vmap wire transport is bit-identical to the scan reference.
     ``like`` is the params template the decoder shapes against."""
-    payload_c = jax.tree.map(_maybe_replicate, payload_c)
+    payload_c = jax.tree.map(hint, payload_c)
     zero = lambda: jax.tree.map(lambda x: jnp.zeros(x.shape, _F32), like)
     acc0 = (zero(), zero(), zero())
 
@@ -456,9 +446,9 @@ def sparse_independent_gather_sum(tree_c, alpha, weights, value_dtype=None,
                                  sort_free=sort_free)
         if value_dtype is not None:
             vals = vals.astype(jnp.dtype(value_dtype))
-        vals = _maybe_replicate(vals)
-        idx = _maybe_replicate(idx)
-        valid = _maybe_replicate(valid)
+        vals = hint(vals)
+        idx = hint(idx)
+        valid = hint(valid)
         return _scatter_weighted(vals, idx, valid, weights, n) \
             .reshape(x_c.shape[1:])
 

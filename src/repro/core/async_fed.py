@@ -61,8 +61,8 @@ from jax.sharding import PartitionSpec
 from repro.core import aggregate, compressors, wire
 from repro.core.compressors import DIAG_KEYS, Deltas
 from repro.core.fed import (
-    FedConfig, FedState, active_client_count, make_client_step,
-    make_server_apply,
+    FedConfig, FedState, active_client_count, client_region_axes,
+    make_client_step, make_server_apply,
 )
 from repro.data.churn import ChurnConfig, ChurnModel
 
@@ -155,11 +155,9 @@ def make_mesh_cohort_exec(fed: FedConfig, loss_fn: Callable, has_cs: bool,
     """shard_map realization of the cohort exec: one spatial client per
     device row over ``fed.client_axes``, exactly the MANUAL region of
     ``fed.round_shardmap``.  ``mesh`` may be omitted if an ambient mesh
-    is active via ``repro.compat.set_mesh``.  The group's leading axis G
+    is active via ``jax.set_mesh``.  The group's leading axis G
     must equal the client-axes device count — the host pads smaller
     groups."""
-    from repro.compat import shard_map
-
     client_step = make_client_step(fed, loss_fn, comp)
     caxes = tuple(fed.client_axes)
     cax = caxes if len(caxes) > 1 else caxes[0]
@@ -179,11 +177,11 @@ def make_mesh_cohort_exec(fed: FedConfig, loss_fn: Callable, has_cs: bool,
             lambda x: PartitionSpec(cax, *([None] * (x.ndim - 1))), tree)
         mets_spec = {k: PartitionSpec(cax)
                      for k in list(DIAG_KEYS) + ["loss"]}
-        sW, sM, sV, ncs, mets = shard_map(
-            body, mesh,
+        sW, sM, sV, ncs, mets = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(rep(W), rep(M), rep(V), stk(batches), stk(cstates)),
             out_specs=(stk(W), stk(W), stk(W), stk(cstates), mets_spec),
-            axis_names=frozenset(caxes),
+            axis_names=client_region_axes(caxes, mesh),
             check_vma=False,
         )(W, M, V, batches, cstates)
         return sW, sM, sV, (ncs if has_cs else None), mets

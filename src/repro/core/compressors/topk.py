@@ -87,9 +87,9 @@ class _TopKBase(Compressor):
         # wire value streams ship as f32 — exact only at q = 32
         return self.q_bits == wire.VALUE_BITS
 
-    def _mask_capacity(self, sizes) -> int:
-        return wire.mask_value_capacity(sizes, self.alpha,
-                                        self.mask_scope, self.exact_topk)
+    def _mask_capacity(self, sizes) -> tuple:
+        return wire.mask_leaf_capacities(sizes, self.alpha,
+                                         self.mask_scope, self.exact_topk)
 
     def _pack_wire(self, sW, sM, sV, sizes):
         raise NotImplementedError

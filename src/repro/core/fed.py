@@ -194,6 +194,18 @@ def client_state_pspecs(client_state, param_pspecs, client_axes):
     return spec_for(client_state)
 
 
+def client_region_axes(client_axes, mesh=None) -> frozenset:
+    """Mesh axes the per-client shard_map region is MANUAL over: the
+    client axes plus every axis of size 1 (``mesh`` defaults to the one
+    set by ``jax.set_mesh``).  A size-1 axis partitions nothing, and the
+    Pallas kernels in the client step compile only where no mesh axis is
+    left to the automatic partitioner."""
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    ones = {a for a, n in zip(mesh.axis_names, mesh.axis_sizes) if n == 1}
+    return frozenset(client_axes) | ones
+
+
 # ---------------------------------------------------------------------------
 # Local training
 # ---------------------------------------------------------------------------
@@ -441,7 +453,8 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
     def round_shardmap(state: FedState, batches, weights):
         """Spatial clients, production path: the per-client local-training
         region runs under shard_map MANUAL over the client mesh axes (auto
-        over "model"), so divergent client replicas are structurally
+        over a "model" axis larger than 1: ``client_region_axes``), so
+        divergent client replicas are structurally
         per-device — GSPMD cannot replicate them (the pure-vmap formulation
         showed 10-100x memory blow-ups at scale).  Per-client compressor
         state (EF residuals under ``client_state["comp"]``, plus the
@@ -450,8 +463,6 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
         exactly as under scan/vmap, and leaves the region still sharded —
         it never materializes unsharded.  Aggregation then runs in the
         global view (dense) or via the injected shard_map transport."""
-        from repro.compat import shard_map
-
         W, M, V = state.W, state.M, state.V
         cs = state.client_state
         has_cs = cs is not None
@@ -476,12 +487,12 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
                      for k in list(DIAG_KEYS) + ["loss"]}
         # cs=None is an empty pytree: its spec entry is None and the body's
         # tree.maps over it are no-ops, so the stateless path is unchanged
-        sW, sM, sV, new_cs, mets = shard_map(
+        sW, sM, sV, new_cs, mets = jax.shard_map(
             body,
             in_specs=(rep(W), rep(M), rep(V), stk(batches),
                       PartitionSpec(None), stk(cs)),
             out_specs=(stk(W), stk(W), stk(W), stk(cs), mets_spec),
-            axis_names=frozenset(caxes),
+            axis_names=client_region_axes(caxes),
             check_vma=False,
         )(W, M, V, batches, weights, cs)
 

@@ -103,11 +103,13 @@ def aligned_total(sizes: Sequence[int]) -> int:
     return -(-t // ALIGN_ELEMS) * ALIGN_ELEMS
 
 
-def mask_value_capacity(sizes: Sequence[int], alpha: float,
-                        mask_scope: str = "per_tensor",
-                        exact_topk: bool = True) -> int:
+def mask_leaf_capacities(sizes: Sequence[int], alpha: float,
+                         mask_scope: str = "per_tensor",
+                         exact_topk: bool = True) -> Tuple[int, ...]:
     """Static worst-case population of one top-k mask over a tree with
-    leaf ``sizes`` — the capacity of each compacted value stream.
+    leaf ``sizes``: one entry per leaf for ``per_tensor`` masks, one for
+    the whole tree for ``global`` ones.  Their sum is the capacity of
+    each compacted value stream.
 
     Mirrors the mask constructions in ``core/sparsify``: exact masks
     keep ``k_for`` per tensor (per-BLOCK for tensors above the blocked
@@ -124,8 +126,8 @@ def mask_value_capacity(sizes: Sequence[int], alpha: float,
 
     cap = cap_exact if exact_topk else cap_thresh
     if mask_scope == "per_tensor":
-        return sum(cap(int(n)) for n in sizes)
-    return cap(int(sum(int(n) for n in sizes)))
+        return tuple(cap(int(n)) for n in sizes)
+    return (cap(int(sum(int(n) for n in sizes))),)
 
 
 def mask_wire_bits(sizes: Sequence[int], alpha: float,
@@ -135,7 +137,7 @@ def mask_wire_bits(sizes: Sequence[int], alpha: float,
     aligned slot) + K f32 values per stream; one bitmap for the shared
     (SSM) layout, three for the independent (Top) layout."""
     t32 = aligned_total(sizes)
-    cap = mask_value_capacity(sizes, alpha, mask_scope, exact_topk)
+    cap = sum(mask_leaf_capacities(sizes, alpha, mask_scope, exact_topk))
     if shared:
         return t32 + 3 * cap * VALUE_BITS
     return 3 * (t32 + cap * VALUE_BITS)
@@ -258,6 +260,29 @@ def _support_positions(flat_support):
     return jnp.cumsum(flat_support.astype(jnp.int32)) - 1
 
 
+def _capped_support(layout: S.PackedLayout, support,
+                    capacity: Sequence[int]):
+    """The support a payload ships: the first ``capacity[i]`` supported
+    slots of leaf ``i`` (flat order), or of the whole buffer when
+    ``capacity`` has one entry (``global`` masks).
+
+    A threshold mask can over-select past its contracted capacity (tied
+    magnitudes, as in bf16 deltas).  Capping per leaf drops the overflow
+    inside the leaf that caused it — as the shard_map transport does
+    (``aggregate._local_pack``) — instead of dropping the last leaves of
+    the tree, and the bitmap then marks exactly the shipped values."""
+    flat = support.reshape(-1)
+    if len(capacity) == 1:
+        kept = flat & (_support_positions(flat) < capacity[0])
+        return kept.reshape(support.shape)
+    assert len(capacity) == layout.num_leaves, capacity
+    parts = [flat[off:off + p] & (_support_positions(flat[off:off + p]) < c)
+             for off, p, c in zip(layout.offsets, layout.padded, capacity)]
+    # the alignment tail past the last leaf holds no support
+    parts.append(flat[layout.total:])
+    return jnp.concatenate(parts).reshape(support.shape)
+
+
 def pack_bits_1d(bits) -> jax.Array:
     """(n,) bool/int bitmap -> (ceil(n/32),) uint32, bit ``i`` of word
     ``w`` = slot ``32 w + i``.  Pure jnp on an arbitrary-length vector —
@@ -288,13 +313,14 @@ def unpack_bits_1d(words, n: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def pack_shared_mask(sW, sM, sV, capacity: int) -> WirePayload:
+def pack_shared_mask(sW, sM, sV, capacity: Sequence[int]) -> WirePayload:
     """FedAdam-SSM wire: one bitmap of the UNION support of the three
     sparse carriers + three compacted value streams.
 
-    The union is contained in the shared mask (so ``<= capacity``), and
-    re-encoding a decoded triple reproduces the same union — packing is
-    idempotent, which is what lets the async driver buffer payloads."""
+    ``capacity``: :func:`mask_leaf_capacities`; the union is capped to
+    it (:func:`_capped_support`).  Re-encoding a decoded triple reproduces
+    the same union — packing is idempotent, which is what lets the async
+    driver buffer payloads."""
     w_leaves, _ = _f32_leaves(sW)
     m_leaves, _ = _f32_leaves(sM)
     v_leaves, _ = _f32_leaves(sV)
@@ -302,15 +328,17 @@ def pack_shared_mask(sW, sM, sV, capacity: int) -> WirePayload:
     wp = _pack_aligned(layout, w_leaves)
     mp = _pack_aligned(layout, m_leaves)
     vp = _pack_aligned(layout, v_leaves)
-    support = (wp != 0) | (mp != 0) | (vp != 0)
+    support = _capped_support(layout, (wp != 0) | (mp != 0) | (vp != 0),
+                              capacity)
     words = _pack_mask_bits(support.astype(jnp.int32))
     flat_sup = support.reshape(-1)
     pos = _support_positions(flat_sup)
+    total = sum(capacity)
     return WirePayload(
         words=(words,),
-        values=(_compact(flat_sup, pos, wp, capacity),
-                _compact(flat_sup, pos, mp, capacity),
-                _compact(flat_sup, pos, vp, capacity)),
+        values=(_compact(flat_sup, pos, wp, total),
+                _compact(flat_sup, pos, mp, total),
+                _compact(flat_sup, pos, vp, total)),
         scales=())
 
 
@@ -330,19 +358,21 @@ def unpack_shared_mask(payload: WirePayload, like):
     return tuple(outs)
 
 
-def pack_independent_mask(sW, sM, sV, capacity: int) -> WirePayload:
+def pack_independent_mask(sW, sM, sV,
+                          capacity: Sequence[int]) -> WirePayload:
     """FedAdam-Top wire: three (bitmap, value stream) pairs — each
-    tensor's own support."""
+    tensor's own support, capped as in :func:`pack_shared_mask`."""
     words, values = [], []
+    total = sum(capacity)
     for tree in (sW, sM, sV):
         leaves, _ = _f32_leaves(tree)
         layout = _layout_for(leaves)
         xp = _pack_aligned(layout, leaves)
-        support = xp != 0
+        support = _capped_support(layout, xp != 0, capacity)
         flat_sup = support.reshape(-1)
         pos = _support_positions(flat_sup)
         words.append(_pack_mask_bits(support.astype(jnp.int32)))
-        values.append(_compact(flat_sup, pos, xp, capacity))
+        values.append(_compact(flat_sup, pos, xp, total))
     return WirePayload(words=tuple(words), values=tuple(values), scales=())
 
 

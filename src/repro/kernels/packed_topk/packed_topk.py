@@ -8,17 +8,17 @@ tile-aligned packed buffer (layout: core/sparsify.PackedLayout) so the
 whole-model compress is exactly TWO launches:
 
   launch 1 (``_hist_kernel``)  — segmented log2 histogram: each (8, 128)
-      block accumulates count(|x| >= edge_j) for its segment's 32
-      scalar-prefetch-indexed bin edges into a VMEM-resident (L, 32)
-      accumulator (rows = segments; one row for scope="global").
+      block accumulates count(|x| >= edge_j) for its segment's 32 bin
+      edges into an SMEM (L, 32) accumulator (rows = segments; one row
+      for scope="global"), indexed by the prefetched segment id.
   host refine (no launch)      — the CDF bracket (first bin with count
       >= k) and the 32 linear-refine candidates are derived from the
       (L, 32) histogram with the SAME eager jnp arithmetic as the
       per-leaf ``select_tau_kernel``, so the candidate taus are
       bit-identical to the per-leaf path's.
   launch 2 (``_make_apply_kernel``) — a (2, nb) two-sweep grid: sweep 0
-      counts |score| against the prefetched refine candidates into VMEM
-      scratch; sweep 1 PICKS tau per segment from the completed counts
+      counts |score| against the refine candidates into SMEM scratch;
+      sweep 1 PICKS tau per segment from the completed counts
       (a select, not arithmetic — so tau is bit-exact vs per-leaf) and
       streams mask-apply x3 + ``value_dtype`` wire cast + error-feedback
       residual, extending kernels/ssm_apply's fused structure.
@@ -58,23 +58,26 @@ BLOCK_ELEMS = SUBLANES * LANES
 N_BINS = 32
 
 
+def _zero_smem(ref):
+    def body(r, carry):
+        ref[r] = jnp.float32(0)
+        return carry
+    lax.fori_loop(0, ref.shape[0], body, 0)
+
+
 def _hist_kernel(seg_ref, e_ref, x_ref, c_ref):
     i = pl.program_id(0)
-    seg = seg_ref[i]
+    base = seg_ref[i] * N_BINS
     a = jnp.abs(x_ref[...].astype(jnp.float32))
-    edges = e_ref[...]                               # (1, N_BINS)
 
     @pl.when(i == 0)
     def _init():
-        c_ref[...] = jnp.zeros_like(c_ref)
+        _zero_smem(c_ref)
 
     # unrolled over the N_BINS candidates: VPU reductions in registers,
-    # then one accumulate into this segment's histogram row
-    cols = [jnp.sum((a >= edges[0, j]).astype(jnp.float32))
-            for j in range(N_BINS)]
-    row = jnp.stack(cols).reshape(1, N_BINS)
-    cur = pl.load(c_ref, (pl.ds(seg, 1), slice(None)))
-    pl.store(c_ref, (pl.ds(seg, 1), slice(None)), cur + row)
+    # each added into this segment's SMEM histogram row
+    for j in range(N_BINS):
+        c_ref[base + j] += jnp.sum((a >= e_ref[base + j]).astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -82,31 +85,27 @@ def packed_hist_2d(xp, seg_ids, edges, *, interpret: bool = True):
     """Segmented histogram over a packed (R, LANES) buffer.
 
     ``seg_ids``: (nb,) int32 segment of each (8, 128) block (scalar
-    prefetch — it also drives the edge-row BlockSpec index map);
-    ``edges``: (L, N_BINS) descending per-segment candidates.  Returns
-    (L, N_BINS) f32 counts of |x| >= edge_j per segment.  ONE launch.
+    prefetch); ``edges``: (L, N_BINS) descending per-segment candidates.
+    Returns (L, N_BINS) f32 counts of |x| >= edge_j per segment.  ONE
+    launch.  Edges and counts live flat in SMEM, indexed by segment.
     """
     nb = xp.shape[0] // SUBLANES
     L = edges.shape[0]
-    return pl.pallas_call(
+    counts = pl.pallas_call(
         _hist_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nb,),
             in_specs=[
-                # one segment's edge row, picked by the prefetched seg id
-                pl.BlockSpec(  # repro-lint: disable=pallas-contract
-                    (1, N_BINS), lambda i, seg: (seg[i], 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(BLOCK, lambda i, seg: (i, 0)),
             ],
-            # deliberately sub-tile: the (L, N_BINS) histogram rows are
-            # revisited every grid step, not streamed
-            out_specs=pl.BlockSpec(  # repro-lint: disable=pallas-contract
-                (L, N_BINS), lambda i, seg: (0, 0)),
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
-        out_shape=jax.ShapeDtypeStruct((L, N_BINS), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((L * N_BINS,), jnp.float32),
         interpret=interpret,
-    )(seg_ids, edges, xp)
+    )(seg_ids, edges.reshape(-1), xp)
+    return counts.reshape(L, N_BINS)
 
 
 def _make_apply_kernel(n_streams: int, has_score: bool,
@@ -115,7 +114,10 @@ def _make_apply_kernel(n_streams: int, has_score: bool,
     scalar prefetch  seg_ids, ks, ns
     inputs           taus2 row, [score?], x_0 .. x_{n_streams-1}
     outputs          s_0 .. s_{n_streams-1}, [err?], taus, counts
-    scratch          (L, N_BINS) refine-count accumulator
+    scratch          (L * N_BINS,) SMEM refine-count accumulator
+
+    The candidates, the counts and the picked taus/counts are scalars
+    indexed by segment, so all of them live flat in SMEM.
 
     Sweep p=0 counts |score| >= taus2_j into the scratch row of this
     block's segment; sweep p=1 picks tau (first candidate whose count
@@ -137,35 +139,35 @@ def _make_apply_kernel(n_streams: int, has_score: bool,
         p = pl.program_id(0)
         i = pl.program_id(1)
         seg = seg_ref[i]
+        base = seg * N_BINS
         a = jnp.abs(score_ref[...].astype(jnp.float32))
-        taus2 = t2_ref[...]                          # (1, N_BINS)
 
         @pl.when((p == 0) & (i == 0))
         def _init():
-            c2_ref[...] = jnp.zeros_like(c2_ref)
+            _zero_smem(c2_ref)
 
         @pl.when(p == 0)
         def _count():
-            cols = [jnp.sum((a >= taus2[0, j]).astype(jnp.float32))
-                    for j in range(N_BINS)]
-            row = jnp.stack(cols).reshape(1, N_BINS)
-            cur = pl.load(c2_ref, (pl.ds(seg, 1), slice(None)))
-            pl.store(c2_ref, (pl.ds(seg, 1), slice(None)), cur + row)
+            for j in range(N_BINS):
+                c2_ref[base + j] += jnp.sum(
+                    (a >= t2_ref[base + j]).astype(jnp.float32))
 
         @pl.when(p == 1)
         def _apply():
             k = ks_ref[seg]
             n = ns_ref[seg]
-            c2 = pl.load(c2_ref, (pl.ds(seg, 1), slice(None)))
-            iota = lax.broadcasted_iota(jnp.int32, (1, N_BINS), 1)
-            idx2 = jnp.argmax(c2 >= k)
-            # scalar pick from a (1, N_BINS) row — a select, not
-            # arithmetic, so tau is bitwise one of the prefetched
-            # candidates (the bit-exactness hinge; see module docstring)
-            sel = lambda row, j: jnp.sum(jnp.where(iota == j, row, 0.0))
-            tau = sel(taus2, idx2)
-            cnt = sel(c2, idx2)
-            tau = jnp.where(k >= n, jnp.zeros((), jnp.float32), tau)
+            # first candidate whose count reaches k (candidate 0 when
+            # none does), by scalar selects — a pick, not arithmetic, so
+            # tau is bitwise one of the prefetched candidates (the
+            # bit-exactness hinge; see module docstring)
+            tau = t2_ref[base]
+            cnt = c2_ref[base]
+            for j in reversed(range(N_BINS)):
+                c_j = c2_ref[base + j]
+                hit = c_j >= k
+                tau = jnp.where(hit, t2_ref[base + j], tau)
+                cnt = jnp.where(hit, c_j, cnt)
+            tau = jnp.where(k >= n, jnp.float32(0), tau)
             cnt = jnp.where(k >= n, n, cnt)
 
             keep = a >= tau
@@ -182,10 +184,8 @@ def _make_apply_kernel(n_streams: int, has_score: bool,
                 outs[nxt][...] = (x0.astype(jnp.float32)
                                   - s0.astype(jnp.float32)).astype(x0.dtype)
                 nxt += 1
-            pl.store(outs[nxt], (pl.ds(seg, 1), pl.ds(0, 1)),
-                     tau.reshape(1, 1))
-            pl.store(outs[nxt + 1], (pl.ds(seg, 1), pl.ds(0, 1)),
-                     cnt.reshape(1, 1))
+            outs[nxt][seg] = tau
+            outs[nxt + 1][seg] = cnt
 
     return kernel
 
@@ -213,32 +213,29 @@ def packed_apply_2d(taus2, seg_ids, ks, ns, streams, sp=None, *,
     # maps collapse to block 0 there so their HBM traffic happens once
     stream_spec = pl.BlockSpec(BLOCK, lambda p, i, *s: (i, 0))
     lazy_spec = pl.BlockSpec(BLOCK, lambda p, i, *s: (i * p, 0))
-    row_spec = pl.BlockSpec(  # repro-lint: disable=pallas-contract
-        (L, 1), lambda p, i, *s: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     ins = ([sp] if has_score else []) + list(streams)
-    in_specs = [
-        pl.BlockSpec(  # repro-lint: disable=pallas-contract
-            (1, N_BINS), lambda p, i, seg, *s: (seg[i], 0)),
-    ]
+    in_specs = [smem]
     if has_score:
         in_specs += [stream_spec] + [lazy_spec] * n_streams
     else:
         in_specs += [stream_spec] + [lazy_spec] * (n_streams - 1)
     n_data_out = n_streams + (1 if with_residual else 0)
-    out_specs = tuple([lazy_spec] * n_data_out + [row_spec, row_spec])
+    out_specs = tuple([lazy_spec] * n_data_out + [smem, smem])
     out_shape = tuple(
         jax.ShapeDtypeStruct(t.shape, t.dtype)
         for t in streams + ((streams[0],) if with_residual else ())
-    ) + (jax.ShapeDtypeStruct((L, 1), jnp.float32),) * 2
-    return pl.pallas_call(
+    ) + (jax.ShapeDtypeStruct((L,), jnp.float32),) * 2
+    *data, taus, counts = pl.pallas_call(
         _make_apply_kernel(n_streams, has_score, with_residual, value_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(2, nb),
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((L, N_BINS), jnp.float32)],
+            scratch_shapes=[pltpu.SMEM((L * N_BINS,), jnp.float32)],
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(seg_ids, ks, ns, taus2, *ins)
+    )(seg_ids, ks, ns, taus2.reshape(-1), *ins)
+    return (*data, taus.reshape(L, 1), counts.reshape(L, 1))

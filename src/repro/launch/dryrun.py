@@ -24,7 +24,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro import roofline as RL
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.launch import steps as ST
@@ -49,11 +48,11 @@ def run_one(arch: str, shape_name: str, mesh_name: str, out_dir: Path,
 
     bundle = ST.build_step(cfg, mesh, shape_name, **build_kw)
     t0 = time.time()
-    with compat.set_mesh(mesh):
-        jfn = compat.jit(bundle.fn,
-                         in_shardings=bundle.in_shardings,
-                         out_shardings=bundle.out_shardings,
-                         donate_argnums=bundle.donate_argnums)
+    with jax.set_mesh(mesh):
+        jfn = jax.jit(bundle.fn,
+                      in_shardings=bundle.in_shardings,
+                      out_shardings=bundle.out_shardings,
+                      donate_argnums=bundle.donate_argnums)
         lowered = jfn.lower(*bundle.args_sds)
         t_lower = time.time() - t0
         t0 = time.time()

@@ -1,19 +1,24 @@
 """FL training driver: any zoo architecture x any FedAdam algorithm.
 
-Runs for real on whatever devices exist (CPU here; the production mesh is
-exercised via dryrun.py).  Examples:
+Runs on whatever devices JAX finds: the published widths on a TPU, or
+``--smoke`` (reduced widths) on the CPU, where the Pallas kernels run in
+interpret mode.  Examples:
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch starcoder2-3b --smoke --rounds 5 --algorithm fedadam_ssm
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch mamba2-1-3b --smoke --rounds 3 --algorithm fedadam_top
+
+``train(parse_args([...]))`` runs the same driver in-process and returns
+the per-round metrics (chip_smoke.py does this).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
-from pathlib import Path
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +29,7 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.core import FedConfig, fed_init, make_compressor, make_fl_round
 from repro.core.compressors import available as available_algorithms
 from repro.data import synthetic_tokens, synthetic_frontend_embeds
+from repro.launch.cache import enable_compile_cache
 from repro.models import init_params, loss_fn
 from repro.optim import AdamHyper
 
@@ -46,7 +52,7 @@ def build_client_batches(cfg, n_clients, batch_size, seq_len, *, seed=0,
     return batch
 
 
-def main() -> None:
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--algorithm", default="fedadam_ssm",
@@ -60,7 +66,8 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config (CPU-runnable)")
+                    help="reduced widths for CPU runs and tests (default: "
+                         "the config's published widths)")
     ap.add_argument("--iid", action="store_true")
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--kernel-adam", action="store_true")
@@ -85,8 +92,24 @@ def main() -> None:
     ap.add_argument("--churn-jitter", type=int, default=0)
     ap.add_argument("--churn-straggler-prob", type=float, default=0.0)
     ap.add_argument("--churn-drop-prob", type=float, default=0.0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+@dataclasses.dataclass
+class TrainRun:
+    """What one training run produced.  ``rounds``: per-round dicts with
+    ``loss`` and ``uplink_bits`` (plus ``seconds``, wall time blocked on
+    the result, for the synchronous driver).  ``compiled`` and
+    ``compile_seconds``: the synchronous round as compiled before its
+    first call (None for the async driver)."""
+    rounds: List[dict]
+    state: Any
+    compile_seconds: Optional[float] = None
+    compiled: Any = None
+
+
+def train(args: argparse.Namespace) -> TrainRun:
+    """Run the FL trainer described by ``args`` (see :func:`parse_args`)."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
@@ -103,6 +126,9 @@ def main() -> None:
         sparsify_backend=args.sparsify_backend,
         participation=args.participation)
     comp = make_compressor(fed)
+    dev = jax.devices()[0]
+    print(f"[train] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}")
     print(f"[train] {cfg.name}: {n_params/1e6:.2f}M params, "
           f"{args.clients} clients, L={args.local_epochs}, "
           f"alpha={args.alpha}, algo={args.algorithm} "
@@ -135,8 +161,11 @@ def main() -> None:
                                      args.seq, non_iid=not args.iid)
         t0 = time.time()
         state, mets = run(state, batch, rounds=args.rounds)
+        result = TrainRun(rounds=[], state=state)
         for r, (loss_v, bits) in enumerate(zip(mets["loss_per_step"],
                                                mets["bits_per_step"])):
+            result.rounds.append(dict(loss=float(loss_v),
+                                      uplink_bits=float(bits)))
             print(f"[round {r:3d}] loss={loss_v:.4f} "
                   f"uplink={bits/8e6:.2f} MB")
         print(f"[train] async: {mets['server_steps']} server steps, "
@@ -145,23 +174,41 @@ def main() -> None:
               f"total uplink={float(mets['uplink_bits'])/8e6:.2f} MB "
               f"({time.time()-t0:.1f}s)")
     else:
-        round_fn = jax.jit(make_fl_round(fed, loss))
-        for r in range(args.rounds):
-            batch = build_client_batches(cfg, args.clients, args.batch,
-                                         args.seq, seed=r,
-                                         non_iid=not args.iid)
-            t0 = time.time()
-            state, mets = round_fn(state, batch)
+        batches = [build_client_batches(cfg, args.clients, args.batch,
+                                        args.seq, seed=r,
+                                        non_iid=not args.iid)
+                   for r in range(args.rounds)]
+        t0 = time.perf_counter()
+        round_fn = jax.jit(make_fl_round(fed, loss)).lower(
+            state, batches[0]).compile()
+        result = TrainRun(rounds=[], state=state, compiled=round_fn,
+                          compile_seconds=time.perf_counter() - t0)
+        print(f"[train] compiled the round in "
+              f"{result.compile_seconds:.1f}s")
+        for r, batch in enumerate(batches):
+            t0 = time.perf_counter()
+            state, mets = jax.block_until_ready(round_fn(state, batch))
+            dt = time.perf_counter() - t0
             loss_v = float(jnp.mean(mets["loss"]))
             bits = float(mets["uplink_bits"])
+            result.rounds.append(dict(loss=loss_v, uplink_bits=bits,
+                                      seconds=dt))
             print(f"[round {r:3d}] loss={loss_v:.4f} "
-                  f"uplink={bits/8e6:.2f} MB  ({time.time()-t0:.1f}s)")
+                  f"uplink={bits/8e6:.2f} MB  ({dt:.3f}s)")
+        result.state = state
 
     if args.checkpoint:
         save_fed_state(state, args.checkpoint,
                        meta=dict(arch=cfg.name, algorithm=args.algorithm,
                                  rounds=args.rounds))
         print(f"[train] saved {args.checkpoint}")
+    return result
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    args = parse_args(argv)
+    enable_compile_cache()
+    train(args)
 
 
 if __name__ == "__main__":

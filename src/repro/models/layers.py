@@ -22,6 +22,7 @@ from jax import lax
 
 from repro.configs.base import AttentionSpec, LayerSpec, MoESpec, SSMSpec
 from repro.models.params import P
+from repro.sharding import hint
 
 NEG_INF = -1e9          # finite mask value (see online-softmax notes)
 _F32 = jnp.float32
@@ -374,21 +375,6 @@ def moe_capacity(m: MoESpec, tokens: int) -> int:
     return max(8, -(-c // 8) * 8)                          # round up to 8
 
 
-def _moe_hint(x, *axes):
-    """Best-effort sharding constraint: try the full spec, then a
-    model-only spec, then identity (CPU tests / manual-axis contexts)."""
-    from jax.sharding import PartitionSpec
-    try:
-        return lax.with_sharding_constraint(x, PartitionSpec(*axes))
-    except Exception:
-        try:
-            only_model = tuple(a if a == "model" else None for a in axes)
-            return lax.with_sharding_constraint(
-                x, PartitionSpec(*only_model))
-        except Exception:
-            return x
-
-
 def moe_fwd(p, m: MoESpec, x):
     """x: (b, s, d) -> (y, aux) with load-balance aux loss.
 
@@ -428,12 +414,12 @@ def moe_fwd(p, m: MoESpec, x):
     buf = jnp.take_along_axis(
         x, jnp.maximum(slot_tok - 1, 0)[..., None], axis=1)  # (b, EC, d)
     buf = jnp.where(slot_valid[..., None], buf, 0).reshape(b, E, C, d)
-    buf = _moe_hint(buf, "data", "model", None, None)
+    buf = hint(buf, "data", "model", None, None)
     # expert FFN (gated); E sharded over "model" = expert parallelism
     h = jnp.einsum("becd,edf->becf", buf, p["w_up"])
     g = jnp.einsum("becd,edf->becf", buf, p["w_gate"])
     y = jnp.einsum("becf,efd->becd", jax.nn.silu(g) * h, p["w_down"])
-    y = _moe_hint(y, "data", "model", None, None)
+    y = hint(y, "data", "model", None, None)
     # combine: one (b, s, d) gather per routing slot j < k from the flat
     # (b, E*C, d) buffer.  (Measured alternatives, see EXPERIMENTS.md §Perf:
     # a (b,s*k,d) values-scatter and an explicit (e,c)-indexed gather both

@@ -142,8 +142,13 @@ def plan_for(arch: str) -> DeployPlan:
 
 
 def hint(x, *axes):
-    """with_sharding_constraint if a mesh is ambient, else identity."""
-    try:
-        return lax.with_sharding_constraint(x, PartitionSpec(*axes))
-    except Exception:
+    """``with_sharding_constraint(x, PartitionSpec(*axes))`` over the
+    ambient mesh (``jax.set_mesh``), leaving out the axes that are manual
+    here (inside a shard_map region).  Identity when no mesh is set or
+    every mesh axis is manual; an axis the mesh lacks raises."""
+    mesh = jax.sharding.get_abstract_mesh()
+    manual = set(mesh.manual_axes)
+    if mesh.empty or manual == set(mesh.axis_names):
         return x
+    spec = PartitionSpec(*(None if a in manual else a for a in axes))
+    return lax.with_sharding_constraint(x, spec)

@@ -55,7 +55,9 @@ def _one_device_agg(alpha, shared=True):
     the transport arithmetic (pack, gather, scatter, EF overflow
     feedback) is mesh-size independent, so it unit-tests in-process."""
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     pspec = {"x": P()}
     agg = A.make_shardmap_sparse_aggregate(mesh, pspec, ("data",), alpha,
                                            shared=shared)

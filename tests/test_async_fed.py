@@ -306,7 +306,7 @@ def test_staleness_scale_strictly_penalizes(s, extra, power):
 _SUB = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import FedConfig, fed_init
     from repro.core.async_fed import AsyncConfig, make_async_round
     from repro.data.churn import ChurnConfig, ChurnModel
@@ -333,10 +333,10 @@ _SUB = textwrap.dedent("""
                   n_clients=C, adam=AdamHyper(lr=0.05),
                   error_feedback=True)
         if exec_kind == "shardmap":
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             fed = FedConfig(client_mode="vmap", client_axes=("data",),
                             **kw)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 run = make_async_round(fed, loss_fn, acfg,
                                        churn=ChurnModel(cc, C),
                                        client_exec="shardmap", mesh=mesh)

@@ -282,7 +282,7 @@ def test_stateful_compressor_runs_on_shardmap_driver(algo, kw):
     rounds.  A 1-device client mesh exercises the exact same MANUAL
     region as the multi-device CI mesh (tests/test_fed_equivalence.py
     pins multi-device equivalence)."""
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     params, batches, loss_fn, _ = _toy()
     C = 1
@@ -293,8 +293,8 @@ def test_stateful_compressor_runs_on_shardmap_driver(algo, kw):
     rf = jax.jit(make_fl_round(fed, loss_fn))
     st = fed_init(fed, params)
     assert st.client_state is not None
-    mesh = jax.make_mesh((1,), ("data",))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1,), ("data",))
+    with jax.set_mesh(mesh):
         st, mets = rf(st, one(batches))
         st2, mets = rf(st, one(batches))
     assert st2.client_state is not None, "state dropped by the mesh driver"

@@ -167,7 +167,7 @@ def test_ef_residual_is_carried_not_rezeroed(mode):
     """The round-2 payload must actually SEE round 1's residual: zeroing
     the carried residual between rounds changes the round-2 outcome, on
     the scan reference and on the shard_map mesh driver alike."""
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     params, batches, loss_fn, C = _toy()
     if mode == "shardmap":
@@ -180,7 +180,7 @@ def test_ef_residual_is_carried_not_rezeroed(mode):
                     client_axes=(("data",) if mode == "shardmap"
                                  else None))
     rf = jax.jit(make_fl_round(fed, loss_fn))
-    ctx = compat.set_mesh(jax.make_mesh((1,), ("data",))) \
+    ctx = jax.set_mesh(make_mesh((1,), ("data",))) \
         if mode == "shardmap" else contextlib.nullcontext()
     with ctx:
         st1, _ = rf(fed_init(fed, params), batches)

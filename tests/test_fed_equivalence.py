@@ -41,7 +41,7 @@ STATEFUL = {
 _SUB = textwrap.dedent("""
     import json, os
     import jax, jax.numpy as jnp
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import FedConfig, fed_init, make_fl_round
     from repro.core import comm
     from repro.core import sparsify as S
@@ -61,7 +61,7 @@ _SUB = textwrap.dedent("""
         x, y = b
         return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ALGOS = json.loads(os.environ["EQUIV_ALGOS"])
 
     def run(mode, algo, kw, rounds=3):
@@ -74,7 +74,7 @@ _SUB = textwrap.dedent("""
         assert st.client_state is not None, algo + " is not stateful"
         hist, bits = [], None
         if mode == "vmap":
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 for _ in range(rounds):
                     st, mets = rf(st, batches)
                     hist.append(st)

@@ -17,10 +17,8 @@ import pytest
 
 _REPO = Path(__file__).resolve().parents[1]
 
-# The sharded step builders target the jax >= 0.6 top-level API
-# (jax.shard_map / jax.set_mesh) THROUGH repro.compat, which falls back
-# to jax.experimental.shard_map + a Mesh-context stand-in on older jax
-# (the pinned 0.4.37 container) — so these tests run on both.
+# The sharded step builders use jax.shard_map under a jax.set_mesh
+# context, on meshes with Auto axes (launch/mesh.make_mesh).
 
 
 def _run_sub(code: str) -> dict:
@@ -39,7 +37,6 @@ def _run_sub(code: str) -> dict:
 def test_sharded_train_step_matches_single_device():
     code = textwrap.dedent("""
         import json, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_config, reduce_for_smoke
         from repro.launch import steps as ST
         from repro.launch.mesh import make_test_mesh
@@ -56,9 +53,9 @@ def test_sharded_train_step_matches_single_device():
         batch = {"tokens": jax.random.randint(
             jax.random.PRNGKey(1),
             bundle.args_sds[1]["tokens"].shape, 0, cfg.vocab_size)}
-        with compat.set_mesh(mesh):
-            jfn = compat.jit(bundle.fn, in_shardings=bundle.in_shardings,
-                             out_shardings=bundle.out_shardings)
+        with jax.set_mesh(mesh):
+            jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
+                          out_shardings=bundle.out_shardings)
             st2, mets = jfn(state, batch)
         loss = float(jnp.mean(mets["loss"]))
         wsum = float(sum(jnp.sum(jnp.abs(x.astype(jnp.float32)))
@@ -75,7 +72,6 @@ def test_sharded_train_step_matches_single_device():
 def test_sharded_serve_step_runs():
     code = textwrap.dedent("""
         import json, functools, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_config, reduce_for_smoke
         from repro.launch import steps as ST
         from repro.launch.mesh import make_test_mesh
@@ -88,9 +84,9 @@ def test_sharded_serve_step_runs():
         params = init_params(cfg, jax.random.PRNGKey(0))
         caches = materialize(cache_meta(cfg, 4, 128), jax.random.PRNGKey(1))
         tok = jnp.zeros((4,), jnp.int32)
-        with compat.set_mesh(mesh):
-            jfn = compat.jit(bundle.fn, in_shardings=bundle.in_shardings,
-                             out_shardings=bundle.out_shardings)
+        with jax.set_mesh(mesh):
+            jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
+                          out_shardings=bundle.out_shardings)
             logits, caches = jfn(params, caches, jnp.int32(0), tok)
             logits, _ = jfn(params, caches, jnp.int32(1), tok)
         ok = bool(jnp.isfinite(logits).all())
@@ -109,7 +105,6 @@ def test_sharded_train_step_threads_ef_state():
     client-stacked — nonzero after a round that dropped anything."""
     code = textwrap.dedent("""
         import json, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_config, reduce_for_smoke
         from repro.launch import steps as ST
         from repro.launch.mesh import make_test_mesh
@@ -130,9 +125,9 @@ def test_sharded_train_step_threads_ef_state():
         batch = {"tokens": jax.random.randint(
             jax.random.PRNGKey(1),
             bundle.args_sds[1]["tokens"].shape, 0, cfg.vocab_size)}
-        with compat.set_mesh(mesh):
-            jfn = compat.jit(bundle.fn, in_shardings=bundle.in_shardings,
-                             out_shardings=bundle.out_shardings)
+        with jax.set_mesh(mesh):
+            jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
+                          out_shardings=bundle.out_shardings)
             st2, mets = jfn(state, batch)
             st3, _ = jfn(st2, batch)
         err1 = st2.client_state["comp"]["err"]
@@ -167,7 +162,6 @@ def test_sparse_transport_collectives_present():
     bytes are far below the dense all-reduce of the model."""
     code = textwrap.dedent("""
         import json, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_config, reduce_for_smoke
         from repro.launch import steps as ST
         from repro.launch.mesh import make_test_mesh
@@ -182,9 +176,9 @@ def test_sparse_transport_collectives_present():
             bundle = ST.build_step(cfg, mesh, "train_4k",
                                    algorithm=algo, aggregate=agg,
                                    local_epochs=1, alpha=0.05)
-            with compat.set_mesh(mesh):
-                jfn = compat.jit(bundle.fn, in_shardings=bundle.in_shardings,
-                                 out_shardings=bundle.out_shardings)
+            with jax.set_mesh(mesh):
+                jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
+                              out_shardings=bundle.out_shardings)
                 compiled = jfn.lower(*bundle.args_sds).compile()
             coll = RL.collective_bytes(compiled.as_text(),
                                        bundle.static["loop_trips"])

@@ -77,7 +77,7 @@ def test_shared_mask_wire_roundtrip(shapes, alpha):
     mask = _exact_mask(dW, alpha)
     sp = lambda t: jax.tree.map(lambda x, m: x * m, t, mask)
     sW, sM, sV = sp(dW), sp(dM), sp(dV)
-    cap = wire.mask_value_capacity(_sizes(dW), alpha)
+    cap = wire.mask_leaf_capacities(_sizes(dW), alpha)
     payload = wire.pack_shared_mask(sW, sM, sV, cap)
     rW, rM, rV = wire.unpack_shared_mask(payload, sW)
     assert _biteq((rW, rM, rV), (sW, sM, sV))
@@ -93,10 +93,49 @@ def test_independent_mask_wire_roundtrip(shapes, alpha):
     trees = [_tree(shapes, seed=s) for s in (3, 4, 5)]
     sp = [jax.tree.map(lambda x, m: x * m, t, _exact_mask(t, alpha))
           for t in trees]
-    cap = wire.mask_value_capacity(_sizes(trees[0]), alpha)
+    cap = wire.mask_leaf_capacities(_sizes(trees[0]), alpha)
     payload = wire.pack_independent_mask(*sp, cap)
     out = wire.unpack_independent_mask(payload, sp[0])
     assert _biteq(out, tuple(sp))
+
+
+@pytest.mark.parametrize("layout", ["shared", "independent"])
+def test_mask_overflow_is_capped_per_leaf_like_the_mesh_transport(layout):
+    """A threshold mask that over-selects a leaf past its capacity (tied
+    magnitudes, as in bf16 deltas) loses the overflow inside that leaf
+    only: the next leaf arrives whole, the payload keeps its size, and
+    each decoded leaf equals what the shard_map bitmap transport
+    (``aggregate._local_pack``) delivers for it."""
+    alpha = 0.05
+    tied = jnp.full((4096,), 0.5, _F32)               # every entry selected
+    x = jax.random.normal(jax.random.PRNGKey(0), (3000,))
+    sW = {"a": tied, "b": x * S.topk_mask_exact(x, S.k_for(x.size, alpha))}
+    sM = jax.tree.map(lambda t: 2.0 * t, sW)
+    sV = jax.tree.map(lambda t: 3.0 * t, sW)
+    sizes = _sizes(sW)
+    caps = wire.mask_leaf_capacities(sizes, alpha, exact_topk=False)
+    assert caps[0] < tied.size
+    if layout == "shared":
+        payload = wire.pack_shared_mask(sW, sM, sV, caps)
+        out = wire.unpack_shared_mask(payload, sW)
+    else:
+        payload = wire.pack_independent_mask(sW, sM, sV, caps)
+        out = wire.unpack_independent_mask(payload, sW)
+    assert 8 * wire.payload_nbytes(payload) == wire.mask_wire_bits(
+        sizes, alpha, exact_topk=False, shared=(layout == "shared"))
+    for sent, got in zip((sW, sM, sV), out):
+        assert bool(jnp.all(got["b"] == sent["b"]))
+        kept = jnp.where(jnp.arange(tied.size) < caps[0], sent["a"], 0.0)
+        assert bool(jnp.all(got["a"] == kept))
+        for name, leaf in sent.items():
+            words, pos, keep, kb = aggregate._local_pack(leaf, alpha)
+            mesh = aggregate._expand_vals(
+                words, aggregate._compact_vals(leaf, pos, keep, kb),
+                leaf.size)
+            assert bool(jnp.all(mesh == got[name])), name
+    again = (wire.pack_shared_mask if layout == "shared"
+             else wire.pack_independent_mask)(*out, caps)
+    assert _biteq(again, payload)
 
 
 @settings(max_examples=10, deadline=None)
