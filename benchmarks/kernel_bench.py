@@ -222,7 +222,7 @@ def _wire_rows(add, alpha: float):
         assert 8 * dense_bytes == wire.dense_wire_bits(sizes, 3)
 
         wire_fn = jax.jit(
-            lambda a, b, c: wire.pack_shared_mask(a, b, c, cap))
+            lambda a, b, c: wire.pack_shared_mask(a, b, c, cap)[0])
         t_wire = _time(wire_fn, sW, sM, sV)
         wire_bytes = wire.payload_nbytes(wire_fn(sW, sM, sV))
         assert 8 * wire_bytes == wire.mask_wire_bits(sizes, alpha)

@@ -37,12 +37,14 @@ from jax import lax
 from jax.sharding import PartitionSpec
 
 from repro.core import sparsify as S
+from repro.core import stages
 from repro.kernels.topk_mask.ops import overselect_bound
 from repro.sharding import hint
 
 _F32 = jnp.float32
 
 
+@stages.scoped(stages.FOLD)
 def dense_weighted_sum(tree_c, weights):
     """tree_c: leaves (C, ...); returns weighted sum over C."""
     return jax.tree.map(
@@ -50,6 +52,7 @@ def dense_weighted_sum(tree_c, weights):
                                 axes=(0, 0)), tree_c)
 
 
+@stages.scoped(stages.FOLD)
 def ordered_weighted_sum(tree_c, weights):
     """Weighted sum over the leading client axis with ``round_scan``'s
     exact accumulation order and arithmetic (``acc + w * x.astype(f32)``,
@@ -92,6 +95,7 @@ def _capacity(n, B, alpha):
     return min(size, base + overselect_bound(base))
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def _pack(x_c, n, alpha, *, sort_free: bool = True):
     """Pack the nonzeros of masked dense deltas into a fixed-capacity COO.
 
@@ -125,6 +129,7 @@ def _pack(x_c, n, alpha, *, sort_free: bool = True):
     return vals, idx, valid
 
 
+@stages.scoped(stages.FOLD)
 def _scatter_weighted(vals, idx, valid, weights, n):
     """vals/idx/valid: (C, nb, kb) replicated; dense (n,) weighted sum."""
     C, nb, kb = vals.shape
@@ -195,6 +200,7 @@ def sparse_shared_gather_sum(sW_c, sM_c, sV_c, alpha, weights,
 # fold into its local dense shard: no model-axis communication at all.
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def _local_pack(wf, alpha):
     """wf: (n_loc,) masked dense, device-local.  -> (words, pos, keep, kb):
     the support bitmap word-packed to uint32 + the compaction plan
@@ -211,6 +217,7 @@ def _local_pack(wf, alpha):
     return words, pos, keep, kb
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def _compact_vals(xf, pos, keep, kb):
     """First-kb compaction of ``xf`` onto the support plan (slot kb is
     the overflow drop slot, sliced away)."""
@@ -219,6 +226,7 @@ def _compact_vals(xf, pos, keep, kb):
         xf.astype(_F32), mode="drop")[:kb]
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def _expand_vals(words, vals, n_loc):
     """Inverse of the (bitmap, stream) pack: (nw,) uint32 words + (kb,)
     values -> (n_loc,) f32 dense (capacity-overflow slots decode to 0)."""
@@ -237,7 +245,9 @@ def _gathered_decode_sum(words_g, vals_g, weights, n_loc):
     is bit-identical to the scan reference when nothing overflows."""
     def body(acc, xs):
         wrds, vals, wgt = xs
-        return acc + wgt * _expand_vals(wrds, vals, n_loc), 0.0
+        x = _expand_vals(wrds, vals, n_loc)
+        with jax.named_scope(stages.FOLD):
+            return acc + wgt * x, 0.0
 
     acc, _ = lax.scan(body, jnp.zeros((n_loc,), _F32),
                       (words_g, vals_g, weights.astype(_F32)))
@@ -387,7 +397,8 @@ def wire_gather_sum(compressor, payload_c, like, weights):
         add = lambda a, s: jax.tree.map(
             lambda x, y: x + wgt * y.astype(_F32), a, s)
         aW, aM, aV = acc
-        return (add(aW, sW), add(aM, sM), add(aV, sV)), 0.0
+        with jax.named_scope(stages.FOLD):
+            return (add(aW, sW), add(aM, sM), add(aV, sV)), 0.0
 
     (aW, aM, aV), _ = lax.scan(body, acc0,
                                (payload_c, weights.astype(_F32)))

@@ -59,10 +59,10 @@ from jax import lax
 from jax.sharding import PartitionSpec
 
 from repro.core import aggregate, compressors, wire
-from repro.core.compressors import DIAG_KEYS, Deltas
+from repro.core.compressors import Deltas
 from repro.core.fed import (
-    FedConfig, FedState, active_client_count, client_region_axes,
-    make_client_step, make_server_apply,
+    CLIENT_METRIC_KEYS, FedConfig, FedState, active_client_count,
+    client_region_axes, make_client_step, make_server_apply,
 )
 from repro.data.churn import ChurnConfig, ChurnModel
 
@@ -175,8 +175,7 @@ def make_mesh_cohort_exec(fed: FedConfig, loss_fn: Callable, has_cs: bool,
         rep = lambda tree: jax.tree.map(lambda _: PartitionSpec(), tree)
         stk = lambda tree: jax.tree.map(
             lambda x: PartitionSpec(cax, *([None] * (x.ndim - 1))), tree)
-        mets_spec = {k: PartitionSpec(cax)
-                     for k in list(DIAG_KEYS) + ["loss"]}
+        mets_spec = {k: PartitionSpec(cax) for k in CLIENT_METRIC_KEYS}
         sW, sM, sV, ncs, mets = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep(W), rep(M), rep(V), stk(batches), stk(cstates)),
@@ -325,7 +324,8 @@ class AsyncRoundDriver:
             out.append(dict(
                 sW=pick(sW), sM=pick(sM), sV=pick(sV),
                 ncs=(pick(ncs) if has_cs else None),
-                loss=mets["loss"][i]))
+                loss=mets["loss"][i],
+                counts={k: mets[k][i] for k in wire.COUNT_KEYS}))
         return out
 
     # -- the simulation -------------------------------------------------
@@ -394,6 +394,7 @@ class AsyncRoundDriver:
         bits_total = 0
         bits_per_step: List[int] = []
         loss_per_step: List[float] = []
+        counts_per_step: List[Dict[str, int]] = []
 
         def redispatch(t, c):
             push(t + self.churn.cfg.rejoin_delay, _EV_DISPATCH, c)
@@ -475,6 +476,9 @@ class AsyncRoundDriver:
                     bits_per_step.append(bits_total - sum(bits_per_step))
                     loss_per_step.append(float(np.mean(
                         [float(e["loss"]) for e in buffer])))
+                    counts_per_step.append(
+                        {k: sum(int(e["counts"][k]) for e in buffer)
+                         for k in wire.COUNT_KEYS})
                     events.append((t, "server_step", steps,
                                    [e["stale"] for e in buffer]))
                     buffer = []
@@ -488,6 +492,7 @@ class AsyncRoundDriver:
             "uplink_bits": jnp.asarray(bits_total, _F32),
             "bits_per_step": bits_per_step,
             "loss_per_step": loss_per_step,
+            "counts_per_step": counts_per_step,
             "server_steps": steps,
             "landed": landed,
             "dropped": dropped,

@@ -85,12 +85,15 @@ class Packed(NamedTuple):
     bit-packed :class:`repro.core.wire.WirePayload` realization of the
     carriers — the arrays that actually cross the uplink (``None`` only
     for configurations outside the wire format's layout constants, which
-    fall back to dense transport + analytic accounting)."""
+    fall back to dense transport + analytic accounting).  ``counts``
+    holds a mask payload's ``wire.COUNT_KEYS`` counters (``None`` for
+    schemes without a mask payload); like ``diag``, never transported."""
     W: Any
     M: Any
     V: Any
     diag: Dict[str, jax.Array]
     wire: Any = None
+    counts: Optional[Dict[str, jax.Array]] = None
 
 
 def tree_sub(a, b):

@@ -92,6 +92,7 @@ class _TopKBase(Compressor):
                                          self.mask_scope, self.exact_topk)
 
     def _pack_wire(self, sW, sM, sV, sizes):
+        """``(payload, counts)`` of the carriers (``core/wire.py``)."""
         raise NotImplementedError
 
     def compress(self, deltas: Deltas, state):
@@ -127,17 +128,22 @@ class _TopKBase(Compressor):
             "norm_dm": S.tree_norm(dM),
             "norm_dv": S.tree_norm(dV),
         }
-        packed = Packed(sW, sM, sV, diag, self.pack_wire(Deltas(sW, sM, sV)))
+        payload, counts = self._counted_pack_wire(Deltas(sW, sM, sV))
+        packed = Packed(sW, sM, sV, diag, payload, counts)
         return packed, new_state, self.bits_per_client(tree_size(deltas.W))
+
+    def _counted_pack_wire(self, carriers: Deltas):
+        """``(payload, counts)``, or ``(None, None)`` off the wire."""
+        if not self._wire_ok():
+            return None, None
+        sizes = tuple(x.size for x in jax.tree.leaves(carriers.W))
+        return self._pack_wire(carriers.W, carriers.M, carriers.V, sizes)
 
     def pack_wire(self, carriers: Deltas):
         # idempotent: the sparse carriers' union support IS the mask, so
         # re-encoding a decoded triple reproduces the payload bitwise
         # (what lets the async driver re-materialize landed bytes)
-        if not self._wire_ok():
-            return None
-        sizes = tuple(x.size for x in jax.tree.leaves(carriers.W))
-        return self._pack_wire(carriers.W, carriers.M, carriers.V, sizes)
+        return self._counted_pack_wire(carriers)[0]
 
 
 @dataclasses.dataclass(frozen=True)

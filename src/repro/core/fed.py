@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from repro.core import aggregate, compressors
+from repro.core import aggregate, compressors, stages, wire
 from repro.core.compressors import DIAG_KEYS, Deltas
 from repro.core.compressors.base import tree_add as _tree_add
 from repro.core.compressors.base import tree_sub as _tree_sub
@@ -66,6 +66,10 @@ _F32 = jnp.float32
 #: onebit_adam    — baseline: 1-bit Adam (warmup + frozen precondition)
 #: efficient_adam — baseline: two-way quantized Adam with EF
 ALGORITHMS = compressors.available()
+
+#: Per-client metrics every client step reports (stacked over clients
+#: by the drivers; shard_map regions need the key set static).
+CLIENT_METRIC_KEYS = DIAG_KEYS + wire.COUNT_KEYS + ("loss",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -309,30 +313,36 @@ def make_client_step(fed: FedConfig, loss_fn: Callable,
         extras = {}
 
         if comp.local_update == "sgd":
-            w, loss = _local_sgd(loss_fn, W, batch, fed)
+            with jax.named_scope(stages.LOCAL_TRAIN):
+                w, loss = _local_sgd(loss_fn, W, batch, fed)
             dW = _tree_sub(w, W)
             z = jax.tree.map(jnp.zeros_like, dW)
             deltas = Deltas(dW, z, z)
         elif comp.local_update == "momentum":
-            m_new, loss = _local_momentum(loss_fn, W, M, batch, fed)
+            with jax.named_scope(stages.LOCAL_TRAIN):
+                m_new, loss = _local_momentum(loss_fn, W, M, batch, fed)
             dM = _tree_sub(m_new, M)
             z = jax.tree.map(jnp.zeros_like, dM)
             deltas = Deltas(z, dM, z)
         elif comp.local_update == "local_adam":
             # persistent local moments (never aggregated — the staleness
             # the paper criticizes)
-            w, m, v, loss = _local_adam(loss_fn, W, cstate["m"],
-                                        cstate["v"], batch, fed)
+            with jax.named_scope(stages.LOCAL_TRAIN):
+                w, m, v, loss = _local_adam(loss_fn, W, cstate["m"],
+                                            cstate["v"], batch, fed)
             dW = _tree_sub(w, W)
             z = jax.tree.map(jnp.zeros_like, dW)
             deltas = Deltas(dW, z, z)
             extras = {"m": m, "v": v}
         else:                             # "adam": the FedAdam family
-            w, m, v, loss = _local_adam(loss_fn, W, M, V, batch, fed)
+            with jax.named_scope(stages.LOCAL_TRAIN):
+                w, m, v, loss = _local_adam(loss_fn, W, M, V, batch, fed)
             deltas = Deltas(_tree_sub(w, W), _tree_sub(m, M),
                             _tree_sub(v, V))
 
-        packed, new_comp_state, _bits = comp.compress(deltas, comp_state)
+        with jax.named_scope(stages.COMPRESS):
+            packed, new_comp_state, _bits = comp.compress(deltas,
+                                                          comp_state)
         if cstate is None:
             new_cstate = None
         else:
@@ -340,7 +350,12 @@ def make_client_step(fed: FedConfig, loss_fn: Callable,
             if "comp" in cstate:
                 new_cstate["comp"] = new_comp_state
             new_cstate.update(extras)
-        mets = dict(packed.diag, loss=loss)
+        # the mask counters describe the payload this step ships; the
+        # mesh step (wire_roundtrip=False) ships none of its own
+        ships = emit == "wire" or wire_roundtrip
+        counts = packed.counts if ships and packed.counts is not None \
+            else wire.zero_counts()
+        mets = dict(packed.diag, **counts, loss=loss)
         if emit == "wire":
             assert packed.wire is not None, \
                 f"{comp.name}: emit='wire' but compress built no payload"
@@ -370,6 +385,7 @@ def make_server_apply(fed: FedConfig,
         comp = compressors.make_compressor(fed)
     h = fed.adam
 
+    @stages.scoped(stages.SERVER_STEP)
     def server_apply(W, M, V, aW, aM, aV, wsum):
         mean = lambda t: jax.tree.map(lambda x: x / wsum, t)
         aW, aM, aV = mean(aW), mean(aM), mean(aV)
@@ -444,7 +460,9 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
             add = lambda a, s: jax.tree.map(
                 lambda x, y: x + wgt * y.astype(_F32), a, s)
             ys = (ncs, mets) if has_cs else (0.0, mets)
-            return ((add(aW, sW), add(aM, sM), add(aV, sV)), wsum + wgt), ys
+            with jax.named_scope(stages.FOLD):
+                acc = (add(aW, sW), add(aM, sM), add(aV, sV))
+            return (acc, wsum + wgt), ys
 
         xs = (batches, weights, cs) if has_cs else (batches, weights)
         ((aW, aM, aV), wsum), (new_cs, mets) = lax.scan(body, (acc0, 0.0), xs)
@@ -483,8 +501,7 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
         rep = lambda tree: jax.tree.map(lambda _: PartitionSpec(), tree)
         stk = lambda tree: jax.tree.map(
             lambda x: PartitionSpec(cax, *([None] * (x.ndim - 1))), tree)
-        mets_spec = {k: PartitionSpec(cax)
-                     for k in list(DIAG_KEYS) + ["loss"]}
+        mets_spec = {k: PartitionSpec(cax) for k in CLIENT_METRIC_KEYS}
         # cs=None is an empty pytree: its spec entry is None and the body's
         # tree.maps over it are no-ops, so the stateless path is unchanged
         sW, sM, sV, new_cs, mets = jax.shard_map(
@@ -587,6 +604,7 @@ def make_fl_round(fed: FedConfig, loss_fn: Callable,
         return (aW, aM, aV), wsum, \
             (new_cs if cs is not None else None), mets
 
+    @stages.scoped(stages.ROUND)
     def round_fn(state: FedState, batches, weights=None, rng=None):
         C = fed.n_clients
         if weights is None:

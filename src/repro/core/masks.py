@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sparsify as S
+from repro.core import stages
 
 _F32 = jnp.float32
 
@@ -56,6 +57,7 @@ def shared_score_tree(rule: str, dW, dM, dV):
     raise ValueError(f"unknown shared mask rule {rule!r}")
 
 
+@stages.scoped(stages.SELECT)
 def shared_mask(rule: str, dW, dM, dV, alpha: float,
                 scope: str = "per_tensor", exact: bool = True,
                 backend=None):
@@ -65,6 +67,7 @@ def shared_mask(rule: str, dW, dM, dV, alpha: float,
                              backend=backend)
 
 
+@stages.scoped(stages.SELECT)
 def independent_masks(dW, dM, dV, alpha: float, scope: str = "per_tensor",
                       exact: bool = True, backend=None):
     """FedAdam-Top: three separate Top_k masks."""

@@ -52,6 +52,7 @@ import numpy as np
 from jax import lax
 from jax.flatten_util import ravel_pytree
 
+from repro.core import stages
 from repro.kernels.packed_topk.ops import (
     packed_apply_ef, packed_hist_kernel, packed_mask_apply)
 from repro.kernels.packed_topk.packed_topk import (
@@ -357,6 +358,7 @@ def _segment_absmax(layout: PackedLayout, score_leaves):
     return out
 
 
+@stages.scoped(stages.SELECT)
 def _packed_select_inputs(layout: PackedLayout, score_leaves, score_p,
                           alpha: float):
     """Launch 1 (histogram) + the host-side CDF refine.  Returns the
@@ -477,7 +479,8 @@ def _fused_leaf(score, w, m, v, k: int, value_dtype, with_residual: bool):
     """One leaf of the fused compress: 3-pass tau selection on the score
     (== w when score is None), then ONE fused apply/cast/residual pass.
     Returns (sw, sm, sv, err|None, mask)."""
-    tau, _ = select_tau_kernel(w if score is None else score, k)
+    with jax.named_scope(stages.SELECT):
+        tau, _ = select_tau_kernel(w if score is None else score, k)
     outs = ssm_apply_ef(tau, w, m, v, score,
                         with_residual=with_residual,
                         value_dtype=value_dtype)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sparsify as S
+from repro.core import stages
 from repro.kernels.topk_mask.ops import overselect_bound
 from repro.kernels.wirepack import ops as _wops
 from repro.kernels.wirepack import ref as _wref
@@ -63,6 +64,29 @@ ALIGN_ELEMS = CODE_SUBLANES * LANES
 
 #: All value/scale side streams ship as f32.
 VALUE_BITS = 32
+
+#: Per-client counters of a mask payload, reported beside ``loss`` in
+#: the round's metrics (never part of the payload): entries the mask
+#: selected (union support, before the per-leaf cap), entries the
+#: bitmap(s) ship, and the value slots the stream(s) hold.
+COUNT_KEYS = ("mask_selected", "mask_shipped", "mask_capacity")
+
+
+def zero_counts():
+    """The counters of a scheme that ships no mask payload."""
+    z = jnp.zeros((), jnp.int32)
+    return {k: z for k in COUNT_KEYS}
+
+
+def mask_shares(counts) -> Optional[Tuple[float, float]]:
+    """``(fill, dropped)`` in % from counters summed over clients: the
+    shipped entries' share of the value slots, and the selected entries'
+    share that the per-leaf cap dropped.  ``None`` without a mask
+    payload (zero capacity)."""
+    sel, shipped, cap = (int(counts[k]) for k in COUNT_KEYS)
+    if cap == 0:
+        return None
+    return 100.0 * shipped / cap, 100.0 * (sel - shipped) / max(sel, 1)
 
 
 class WirePayload(NamedTuple):
@@ -262,9 +286,10 @@ def _support_positions(flat_support):
 
 def _capped_support(layout: S.PackedLayout, support,
                     capacity: Sequence[int]):
-    """The support a payload ships: the first ``capacity[i]`` supported
-    slots of leaf ``i`` (flat order), or of the whole buffer when
-    ``capacity`` has one entry (``global`` masks).
+    """``(shipped, counts)``: the support a payload ships — the first
+    ``capacity[i]`` supported slots of leaf ``i`` (flat order), or of
+    the whole buffer when ``capacity`` has one entry (``global`` masks)
+    — and its :data:`COUNT_KEYS` counters (:func:`_counts`).
 
     A threshold mask can over-select past its contracted capacity (tied
     magnitudes, as in bf16 deltas).  Capping per leaf drops the overflow
@@ -273,14 +298,31 @@ def _capped_support(layout: S.PackedLayout, support,
     the tree, and the bitmap then marks exactly the shipped values."""
     flat = support.reshape(-1)
     if len(capacity) == 1:
-        kept = flat & (_support_positions(flat) < capacity[0])
-        return kept.reshape(support.shape)
+        pos = _support_positions(flat)
+        kept = flat & (pos < capacity[0])
+        return kept.reshape(support.shape), _counts([pos[-1] + 1], capacity)
     assert len(capacity) == layout.num_leaves, capacity
-    parts = [flat[off:off + p] & (_support_positions(flat[off:off + p]) < c)
-             for off, p, c in zip(layout.offsets, layout.padded, capacity)]
+    parts, selected = [], []
+    for off, p, c in zip(layout.offsets, layout.padded, capacity):
+        pos = _support_positions(flat[off:off + p])
+        parts.append(flat[off:off + p] & (pos < c))
+        selected.append(pos[-1] + 1)
     # the alignment tail past the last leaf holds no support
     parts.append(flat[layout.total:])
-    return jnp.concatenate(parts).reshape(support.shape)
+    return (jnp.concatenate(parts).reshape(support.shape),
+            _counts(selected, capacity))
+
+
+def _counts(selected, capacity: Sequence[int]):
+    """The :data:`COUNT_KEYS` counters of a capped support from the
+    count each capacity entry selected (the last entry of its prefix
+    sum): the cap ships the first ``capacity[i]`` of entry ``i``'s, so
+    no pass over the slots is needed to count the bitmap."""
+    sel = jnp.stack(selected)
+    caps = jnp.asarray(capacity, jnp.int32)
+    return dict(zip(COUNT_KEYS, (jnp.sum(sel),
+                                 jnp.sum(jnp.minimum(sel, caps)),
+                                 jnp.sum(caps))))
 
 
 def pack_bits_1d(bits) -> jax.Array:
@@ -313,9 +355,12 @@ def unpack_bits_1d(words, n: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def pack_shared_mask(sW, sM, sV, capacity: Sequence[int]) -> WirePayload:
+@stages.scoped(stages.WIRE_ENCODE)
+def pack_shared_mask(sW, sM, sV, capacity: Sequence[int]):
     """FedAdam-SSM wire: one bitmap of the UNION support of the three
-    sparse carriers + three compacted value streams.
+    sparse carriers + three compacted value streams.  Returns
+    ``(payload, counts)``; ``counts`` holds the :data:`COUNT_KEYS`
+    counters, which the payload does not carry.
 
     ``capacity``: :func:`mask_leaf_capacities`; the union is capped to
     it (:func:`_capped_support`).  Re-encoding a decoded triple reproduces
@@ -328,20 +373,22 @@ def pack_shared_mask(sW, sM, sV, capacity: Sequence[int]) -> WirePayload:
     wp = _pack_aligned(layout, w_leaves)
     mp = _pack_aligned(layout, m_leaves)
     vp = _pack_aligned(layout, v_leaves)
-    support = _capped_support(layout, (wp != 0) | (mp != 0) | (vp != 0),
-                              capacity)
+    support, counts = _capped_support(
+        layout, (wp != 0) | (mp != 0) | (vp != 0), capacity)
     words = _pack_mask_bits(support.astype(jnp.int32))
     flat_sup = support.reshape(-1)
     pos = _support_positions(flat_sup)
     total = sum(capacity)
-    return WirePayload(
+    payload = WirePayload(
         words=(words,),
         values=(_compact(flat_sup, pos, wp, total),
                 _compact(flat_sup, pos, mp, total),
                 _compact(flat_sup, pos, vp, total)),
         scales=())
+    return payload, counts
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def unpack_shared_mask(payload: WirePayload, like):
     """Decode to the (sW, sM, sV) triple; ``like`` is any tree with the
     carrier's structure/shapes/dtypes (e.g. the params template)."""
@@ -358,24 +405,31 @@ def unpack_shared_mask(payload: WirePayload, like):
     return tuple(outs)
 
 
-def pack_independent_mask(sW, sM, sV,
-                          capacity: Sequence[int]) -> WirePayload:
+@stages.scoped(stages.WIRE_ENCODE)
+def pack_independent_mask(sW, sM, sV, capacity: Sequence[int]):
     """FedAdam-Top wire: three (bitmap, value stream) pairs — each
-    tensor's own support, capped as in :func:`pack_shared_mask`."""
+    tensor's own support, capped as in :func:`pack_shared_mask`.
+    Returns ``(payload, counts)``, the counters summed over the three
+    tensors."""
     words, values = [], []
     total = sum(capacity)
+    counts = zero_counts()
     for tree in (sW, sM, sV):
         leaves, _ = _f32_leaves(tree)
         layout = _layout_for(leaves)
         xp = _pack_aligned(layout, leaves)
-        support = _capped_support(layout, xp != 0, capacity)
+        support, cnt = _capped_support(layout, xp != 0, capacity)
         flat_sup = support.reshape(-1)
         pos = _support_positions(flat_sup)
         words.append(_pack_mask_bits(support.astype(jnp.int32)))
         values.append(_compact(flat_sup, pos, xp, total))
-    return WirePayload(words=tuple(words), values=tuple(values), scales=())
+        counts = {k: counts[k] + cnt[k] for k in COUNT_KEYS}
+    payload = WirePayload(words=tuple(words), values=tuple(values),
+                          scales=())
+    return payload, counts
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def unpack_independent_mask(payload: WirePayload, like):
     leaves, treedef = jax.tree_util.tree_flatten(like)
     layout = _layout_for(leaves)
@@ -390,6 +444,7 @@ def unpack_independent_mask(payload: WirePayload, like):
     return tuple(outs)
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def pack_sign(carrier) -> WirePayload:
     """1-bit Adam wire: sign bitplane + per-block max-|.| scales of the
     aligned carrier buffer.  Exact for ``sign_quant`` carriers (every
@@ -401,6 +456,7 @@ def pack_sign(carrier) -> WirePayload:
     return WirePayload(words=(words,), values=(), scales=(scales,))
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def unpack_sign(payload: WirePayload, like):
     leaves, treedef = jax.tree_util.tree_flatten(like)
     layout = _layout_for(leaves)
@@ -409,6 +465,7 @@ def unpack_sign(payload: WirePayload, like):
         treedef, _unpack_aligned(layout, buf, leaves))
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def pack_bbit_codes(codes_leaves, scales_leaves, bits: int) -> WirePayload:
     """Efficient-Adam wire: the quantizer's int32 codes word-packed at b
     bits (offset by qmax to unsigned; layout padding encodes code 0,
@@ -420,6 +477,7 @@ def pack_bbit_codes(codes_leaves, scales_leaves, bits: int) -> WirePayload:
                        scales=tuple(s.astype(_F32) for s in scales_leaves))
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def unpack_bbit_codes(payload: WirePayload, like, bits: int):
     """Decode to the dequantized f32 carrier tree (``uniform_decode`` of
     each leaf's codes with its shipped scales)."""
@@ -434,6 +492,7 @@ def unpack_bbit_codes(payload: WirePayload, like, bits: int):
     return jax.tree_util.tree_unflatten(treedef, outs)
 
 
+@stages.scoped(stages.WIRE_ENCODE)
 def pack_dense(trees: Sequence[Any]) -> WirePayload:
     """FedAdam/FedSGD wire: one raveled f32 plane per communicated
     tensor — byte count equals the analytic formula exactly."""
@@ -444,6 +503,7 @@ def pack_dense(trees: Sequence[Any]) -> WirePayload:
     return WirePayload(words=(), values=planes, scales=())
 
 
+@stages.scoped(stages.WIRE_DECODE)
 def unpack_dense(payload: WirePayload, like):
     """Decode each plane back onto the ``like`` tree structure."""
     leaves, treedef = jax.tree_util.tree_flatten(like)

@@ -26,7 +26,8 @@ import numpy as np
 
 from repro.checkpoint import save_fed_state
 from repro.configs import get_config, reduce_for_smoke
-from repro.core import FedConfig, fed_init, make_compressor, make_fl_round
+from repro.core import (
+    FedConfig, fed_init, make_compressor, make_fl_round, wire)
 from repro.core.compressors import available as available_algorithms
 from repro.data import synthetic_tokens, synthetic_frontend_embeds
 from repro.launch.cache import enable_compile_cache
@@ -98,14 +99,27 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
 @dataclasses.dataclass
 class TrainRun:
     """What one training run produced.  ``rounds``: per-round dicts with
-    ``loss`` and ``uplink_bits`` (plus ``seconds``, wall time blocked on
-    the result, for the synchronous driver).  ``compiled`` and
+    ``loss``, ``uplink_bits``, and ``value_fill_share`` and
+    ``mask_dropped_share`` (%, ``wire.mask_shares``; None for schemes
+    without a mask payload), plus ``seconds``, wall time blocked on the
+    result, for the synchronous driver.  ``compiled`` and
     ``compile_seconds``: the synchronous round as compiled before its
     first call (None for the async driver)."""
     rounds: List[dict]
     state: Any
     compile_seconds: Optional[float] = None
     compiled: Any = None
+
+
+def _share_fields(shares) -> dict:
+    fill, dropped = shares if shares is not None else (None, None)
+    return dict(value_fill_share=fill, mask_dropped_share=dropped)
+
+
+def _share_text(shares) -> str:
+    if shares is None:
+        return ""
+    return f" fill={shares[0]:.2f}% dropped={shares[1]:.4f}%"
 
 
 def train(args: argparse.Namespace) -> TrainRun:
@@ -162,12 +176,15 @@ def train(args: argparse.Namespace) -> TrainRun:
         t0 = time.time()
         state, mets = run(state, batch, rounds=args.rounds)
         result = TrainRun(rounds=[], state=state)
-        for r, (loss_v, bits) in enumerate(zip(mets["loss_per_step"],
-                                               mets["bits_per_step"])):
+        for r, (loss_v, bits, counts) in enumerate(zip(
+                mets["loss_per_step"], mets["bits_per_step"],
+                mets["counts_per_step"])):
+            shares = wire.mask_shares(counts)
             result.rounds.append(dict(loss=float(loss_v),
-                                      uplink_bits=float(bits)))
+                                      uplink_bits=float(bits),
+                                      **_share_fields(shares)))
             print(f"[round {r:3d}] loss={loss_v:.4f} "
-                  f"uplink={bits/8e6:.2f} MB")
+                  f"uplink={bits/8e6:.2f} MB{_share_text(shares)}")
         print(f"[train] async: {mets['server_steps']} server steps, "
               f"{mets['landed']} landed / {mets['dropped']} dropped / "
               f"{mets['discarded']} discarded, "
@@ -191,10 +208,13 @@ def train(args: argparse.Namespace) -> TrainRun:
             dt = time.perf_counter() - t0
             loss_v = float(jnp.mean(mets["loss"]))
             bits = float(mets["uplink_bits"])
+            shares = wire.mask_shares(
+                {k: jnp.sum(mets[k]) for k in wire.COUNT_KEYS})
             result.rounds.append(dict(loss=loss_v, uplink_bits=bits,
-                                      seconds=dt))
+                                      seconds=dt, **_share_fields(shares)))
             print(f"[round {r:3d}] loss={loss_v:.4f} "
-                  f"uplink={bits/8e6:.2f} MB  ({dt:.3f}s)")
+                  f"uplink={bits/8e6:.2f} MB{_share_text(shares)}  "
+                  f"({dt:.3f}s)")
         result.state = state
 
     if args.checkpoint:

@@ -30,6 +30,8 @@ def test_train_returns_per_round_metrics():
     for r in run.rounds:
         assert math.isfinite(r["loss"]) and r["uplink_bits"] > 0
         assert r["seconds"] > 0
+        assert 0 < r["value_fill_share"] <= 100
+        assert 0 <= r["mask_dropped_share"] < 100
     assert run.rounds[0]["uplink_bits"] == run.rounds[1]["uplink_bits"]
     assert run.compile_seconds > 0
     assert "HloModule" in run.compiled.as_text()

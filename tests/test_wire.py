@@ -78,12 +78,12 @@ def test_shared_mask_wire_roundtrip(shapes, alpha):
     sp = lambda t: jax.tree.map(lambda x, m: x * m, t, mask)
     sW, sM, sV = sp(dW), sp(dM), sp(dV)
     cap = wire.mask_leaf_capacities(_sizes(dW), alpha)
-    payload = wire.pack_shared_mask(sW, sM, sV, cap)
+    payload, _ = wire.pack_shared_mask(sW, sM, sV, cap)
     rW, rM, rV = wire.unpack_shared_mask(payload, sW)
     assert _biteq((rW, rM, rV), (sW, sM, sV))
     # idempotence: re-encoding the decoded triple reproduces the payload
     # (the async driver's re-materialization relies on this)
-    again = wire.pack_shared_mask(rW, rM, rV, cap)
+    again, _ = wire.pack_shared_mask(rW, rM, rV, cap)
     assert _biteq(again, payload)
 
 
@@ -94,7 +94,7 @@ def test_independent_mask_wire_roundtrip(shapes, alpha):
     sp = [jax.tree.map(lambda x, m: x * m, t, _exact_mask(t, alpha))
           for t in trees]
     cap = wire.mask_leaf_capacities(_sizes(trees[0]), alpha)
-    payload = wire.pack_independent_mask(*sp, cap)
+    payload, _ = wire.pack_independent_mask(*sp, cap)
     out = wire.unpack_independent_mask(payload, sp[0])
     assert _biteq(out, tuple(sp))
 
@@ -116,10 +116,10 @@ def test_mask_overflow_is_capped_per_leaf_like_the_mesh_transport(layout):
     caps = wire.mask_leaf_capacities(sizes, alpha, exact_topk=False)
     assert caps[0] < tied.size
     if layout == "shared":
-        payload = wire.pack_shared_mask(sW, sM, sV, caps)
+        payload, _ = wire.pack_shared_mask(sW, sM, sV, caps)
         out = wire.unpack_shared_mask(payload, sW)
     else:
-        payload = wire.pack_independent_mask(sW, sM, sV, caps)
+        payload, _ = wire.pack_independent_mask(sW, sM, sV, caps)
         out = wire.unpack_independent_mask(payload, sW)
     assert 8 * wire.payload_nbytes(payload) == wire.mask_wire_bits(
         sizes, alpha, exact_topk=False, shared=(layout == "shared"))
@@ -133,8 +133,8 @@ def test_mask_overflow_is_capped_per_leaf_like_the_mesh_transport(layout):
                 words, aggregate._compact_vals(leaf, pos, keep, kb),
                 leaf.size)
             assert bool(jnp.all(mesh == got[name])), name
-    again = (wire.pack_shared_mask if layout == "shared"
-             else wire.pack_independent_mask)(*out, caps)
+    again, _ = (wire.pack_shared_mask if layout == "shared"
+                else wire.pack_independent_mask)(*out, caps)
     assert _biteq(again, payload)
 
 
