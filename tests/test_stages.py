@@ -19,6 +19,7 @@ from repro.core import FedConfig, compressors, fed_init, make_fl_round
 from repro.core import sparsify as S
 from repro.core import stages, wire
 from repro.core.compressors import Deltas
+from repro.kernels.wirepack.ref import unpack_mask_bits_ref
 from repro.optim import AdamHyper
 
 _BF16 = jnp.bfloat16
@@ -117,7 +118,7 @@ def _union_count(trees, shared: bool) -> int:
 
 
 def _bitmap_popcount(payload) -> int:
-    return sum(int(np.asarray(wire._unpack_mask_bits(w)).sum())
+    return sum(int(np.asarray(unpack_mask_bits_ref(w)).sum())
                for w in payload.words)
 
 

@@ -21,6 +21,7 @@ from repro.kernels.fused_adam import fused_adam as fa
 from repro.kernels.packed_topk import packed_topk as pk
 from repro.kernels.ssm_apply import ssm_apply as sa
 from repro.kernels.topk_mask import topk_mask as tm
+from repro.kernels.wirepack import expand as we
 from repro.kernels.wirepack import wirepack as wp
 
 #: Leaf widths (element counts) the kernels see at published widths.
@@ -29,6 +30,10 @@ LEAVES = {
     "starcoder2_3b_embed": 49152 * 3072,
     "starcoder2_3b_mlp": 3072 * 12288,
 }
+
+#: The whole aligned buffer of a whisper-base mask payload (70.66M
+#: parameters) and its value-stream capacity at alpha 0.05.
+WHISPER_ROWS, WHISPER_CAP = 552096, 3745384
 
 
 @pytest.fixture(scope="module")
@@ -119,3 +124,21 @@ def test_fused_adam_compiles(one_chip, leaf):
     r = _rows(LEAVES[leaf], fa.LANES, fa.SUBLANES)
     x = ((r, fa.LANES), jnp.float32)
     _compile(fa.fused_adam_2d, one_chip, ((4,), jnp.float32), x, x, x, x)
+
+
+@pytest.mark.parametrize("n_streams", [1, 3])
+@pytest.mark.parametrize("leaf", [*LEAVES, "whisper_base_buffer"])
+def test_wirepack_expand_compiles(one_chip, leaf, n_streams):
+    if leaf == "whisper_base_buffer":
+        rows, cap = WHISPER_ROWS, WHISPER_CAP
+    else:
+        rows = _rows(LEAVES[leaf], wp.LANES, wp.CODE_SUBLANES)
+        cap = -(-LEAVES[leaf] // 20)
+    words = jax.ShapeDtypeStruct((rows // wp.WORD_BITS, wp.LANES),
+                                 jnp.uint32, sharding=one_chip)
+    streams = tuple(jax.ShapeDtypeStruct((cap,), jnp.float32,
+                                         sharding=one_chip)
+                    for _ in range(n_streams))
+    text = we.expand_streams_2d.lower(
+        words, streams, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text

@@ -99,6 +99,42 @@ def test_independent_mask_wire_roundtrip(shapes, alpha):
     assert _biteq(out, tuple(sp))
 
 
+def _mask_payload(layout, alpha=0.05):
+    """A mask payload over leaves spanning several word tiles, with a
+    threshold-style over-selected leaf so one stream runs full."""
+    x = jax.random.normal(jax.random.PRNGKey(21), (70000,))
+    y = jax.random.normal(jax.random.PRNGKey(22), (1500, 9))
+    exact = lambda v: v * S.topk_mask_exact(v, S.k_for(v.size, alpha))
+    sW = {"a": exact(x), "b": exact(y), "c": jnp.full((3000,), -0.0)}
+    sM = {"a": 2.0 * exact(x), "b": exact(jnp.roll(y, 3)),
+          "c": jnp.full((3000,), 0.25)}
+    sV = jax.tree.map(lambda t: 3.0 * t, sW)
+    caps = wire.mask_leaf_capacities(_sizes(sW), alpha, exact_topk=False)
+    pack = (wire.pack_shared_mask if layout == "shared"
+            else wire.pack_independent_mask)
+    payload, _ = pack(sW, sM, sV, caps)
+    return payload, sW
+
+
+@pytest.mark.parametrize("layout", ["shared", "independent"])
+def test_mask_decode_kernel_equals_jnp_path(layout, monkeypatch):
+    """The tile-local Pallas decode (interpret mode here) is bitwise the
+    jnp ``_expand`` decode of the same payload, shared- and
+    independent-mask layouts alike."""
+    unpack = (wire.unpack_shared_mask if layout == "shared"
+              else wire.unpack_independent_mask)
+    monkeypatch.setenv(S.SPARSIFY_BACKEND_ENV, "reference")
+    payload, like = _mask_payload(layout)
+    want = unpack(payload, like)
+    monkeypatch.setenv(S.SPARSIFY_BACKEND_ENV, "kernel")
+    got = unpack(payload, like)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g).view(np.int32),
+                                      np.asarray(w).view(np.int32))
+    assert any(bool(jnp.any(leaf != 0)) for leaf in jax.tree.leaves(got))
+
+
 @pytest.mark.parametrize("layout", ["shared", "independent"])
 def test_mask_overflow_is_capped_per_leaf_like_the_mesh_transport(layout):
     """A threshold mask that over-selects a leaf past its capacity (tied
