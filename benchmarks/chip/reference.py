@@ -2,7 +2,8 @@
 that decides ``correct``.  It imports nothing of the program.
 
 Model: the decoder stack the program runs for whisper-base and
-starcoder2-3b (configuration files under ``configs/``): RMSNorm, rotary
+starcoder2-3b (configuration files under ``configs/``, and
+``tests/data/`` for a configuration with no cell yet): RMSNorm, rotary
 self-attention with grouped KV heads, cross-attention to an encoder of
 the same layers (whisper), a GELU MLP, an untied head over the padded
 vocabulary, next-token cross-entropy.  Each departure from the published
@@ -28,6 +29,13 @@ Weights are made here from the seed with the program's published
 initialisation recipe (normal 0.02, or 1/sqrt(fan_in) for projections,
 one key per leaf in flattened order).
 
+The round runs as small compiled pieces (:class:`Round`), so that a
+configuration of 535M parameters fits one 16 GB chip: the device holds
+the global state, one client's, one float32 gradient and one leaf's
+float32 temporaries at a time, and each client's kept deltas wait on the
+host in their stored dtype until the last client is done.  The pieces
+compute what one program of the whole round computes, in the same order.
+
 ``mode="fp8"`` is the control: the same reference in float8_e4m3fn
 (per-tensor scale) wherever the configuration holds bfloat16: the
 stored weights, moments and deltas, matmul operands and outputs, norm
@@ -40,11 +48,13 @@ tokens, where the batch holds one).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 F32 = jnp.float32
@@ -326,69 +336,147 @@ def _up(tree):
     return jax.tree.map(lambda x: x.astype(F32), tree)
 
 
-def client_update(c, mix, W, M, V, tokens, embeds, mode="f32",
-                  fault=None):
-    """One client's local Adam steps and shared-mask sparsification.
-    Returns the kept (dW, dM, dV) in float32 and the mean local loss."""
+def _grad(w, tokens, embeds, i, c, mode, fault):
+    """Loss and float32 gradient of client ``i``'s batch at the weights
+    ``w`` (stored dtypes, taken to float32)."""
+    tokens = lax.dynamic_index_in_dim(tokens, i, keepdims=False)
+    if embeds is not None:
+        embeds = lax.dynamic_index_in_dim(embeds, i, keepdims=False)
     if fault == "half_batch" and tokens.shape[0] > 1:
         half = tokens.shape[0] // 2
         tokens = tokens[:half]
         embeds = None if embeds is None else embeds[:half]
     elif fault == "half_batch":                 # one row: half its tokens
         tokens = tokens[:, :tokens.shape[1] // 2]
-    lr = mix["lr"]
-    w, m, v = W, M, V
-    losses = []
-    for _ in range(mix["local_epochs"]):
-        l, g = jax.value_and_grad(loss)(_up(w), tokens, embeds, c, mode)
-        losses.append(l)
-        mf = jax.tree.map(lambda a, b: BETA1 * a.astype(F32)
-                          + (1 - BETA1) * b, m, g)
-        vf = jax.tree.map(lambda a, b: BETA2 * a.astype(F32)
-                          + (1 - BETA2) * b * b, v, g)
-        w = _cast(jax.tree.map(lambda a, mm, vv: a.astype(F32)
-                               - lr * mm / jnp.sqrt(vv + EPS), w, mf, vf), w,
-                  mode)
-        m, v = _cast(mf, m, mode), _cast(vf, v, mode)
-    delta = lambda a, b: _cast(jax.tree.map(
-        lambda x, y: x.astype(F32) - y.astype(F32), a, b), b, mode)
+    return jax.value_and_grad(loss)(_up(w), tokens, embeds, c, mode)
+
+
+def _adam(w, m, v, g, lr, mode):
+    """One leaf's Adam step without bias correction (eps inside the
+    square root), its new values stored in the leaf's dtypes."""
+    mf = BETA1 * m.astype(F32) + (1 - BETA1) * g
+    vf = BETA2 * v.astype(F32) + (1 - BETA2) * g * g
+    w = _cast(w.astype(F32) - lr * mf / jnp.sqrt(vf + EPS), w, mode)
+    return w, _cast(mf, m, mode), _cast(vf, v, mode)
+
+
+def _kept(w, W, m, M, v, V, alpha, mode):
+    """One leaf's (dW, dM, dV), stored in the leaf's dtype, with the
+    entries the shared threshold mask does not keep set to zero."""
+    delta = lambda a, b: _cast(a.astype(F32) - b.astype(F32), b, mode)
     dW, dM, dV = delta(w, W), delta(m, M), delta(v, V)
-    alpha = mix["alpha"]
-    mask = jax.tree.map(
-        lambda w, m_, v_: threshold_mask(
-            w, m_, v_, max(1, int(round(alpha * w.size)))), dW, dM, dV)
-    keep = lambda t: jax.tree.map(
-        lambda x, mk: jnp.where(mk, x.astype(F32), 0.0), t, mask)
-    return keep(dW), keep(dM), keep(dV), jnp.mean(jnp.stack(losses))
+    mask = threshold_mask(dW, dM, dV, max(1, int(round(alpha * dW.size))))
+    return tuple(jnp.where(mask, d, jnp.zeros((), d.dtype))
+                 for d in (dW, dM, dV))
 
 
-def make_round(c: dict, mix: dict, mode: str = "f32",
-               fault: Optional[str] = None):
-    """``round(state, batch) -> (state, per-client losses)``, jitted; the
-    clients run in sequence and their kept deltas are summed in float32."""
+def _apply(T, kept, mode):
+    """One leaf of the new state: ``T + (0 + s_1 + ... + s_n) / n`` in
+    float32, the clients' kept deltas ``kept`` (n, ...) added in order,
+    stored in ``T``'s dtype."""
+    def add(acc, s):
+        return acc + s.astype(F32), None
 
-    def one_round(state, batch):
-        embeds = batch.get("embeds")
+    acc, _ = lax.scan(add, jnp.zeros(T.shape, F32), kept)
+    return _cast(T.astype(F32) + acc / kept.shape[0], T, mode)
 
-        def body(acc, xs):
-            tokens, emb = xs
-            sW, sM, sV, l = client_update(c, mix, state.W, state.M, state.V,
-                                          tokens, emb, mode, fault)
-            add = lambda a, s: jax.tree.map(jnp.add, a, s)
-            return (add(acc[0], sW), add(acc[1], sM), add(acc[2], sV)), l
 
-        zero = jax.tree.map(lambda x: jnp.zeros(x.shape, F32), state.W)
-        (aW, aM, aV), losses = lax.scan(body, (zero, zero, zero),
-                                        (batch["tokens"], embeds))
-        if fault == "unchanged":
+def _mean(losses):
+    return jnp.mean(jnp.stack(losses))
+
+
+class Round:
+    """``Round(c, mix, mode, fault)(state, batch) -> (state, [loss of
+    each client])``: the clients run in sequence and their kept deltas are
+    summed in float32.
+
+    The round runs as a sequence of small compiled pieces, so that the
+    device holds the global state, one client's (w, m, v), its float32
+    gradient and the float32 temporaries of one leaf at a time: the
+    gradient of one local step (``grad``); each leaf's Adam step
+    (``adam``); each leaf's deltas, shared mask and kept deltas
+    (``kept``), which go to the host in the leaf's dtype (exact: they
+    are stored there); and, after the last client, each leaf's sum over
+    the clients and its update (``apply``).  The arithmetic and its
+    order are those of a round in one program: the same expressions per
+    leaf, the float32 sum ``0 + s_1 + s_2 + ...`` in client order."""
+
+    def __init__(self, c: dict, mix: dict, mode: str = "f32",
+                 fault: Optional[str] = None):
+        self.mix, self.fault = mix, fault
+        self.fns = {
+            "grad": jax.jit(functools.partial(_grad, c=c, mode=mode,
+                                              fault=fault)),
+            "adam": jax.jit(functools.partial(_adam, lr=mix["lr"],
+                                              mode=mode)),
+            "kept": jax.jit(functools.partial(_kept, alpha=mix["alpha"],
+                                              mode=mode)),
+            "apply": jax.jit(functools.partial(_apply, mode=mode)),
+            "mean": jax.jit(_mean),
+        }
+
+    def __call__(self, state: State, batch: dict):
+        f = self.fns
+        tokens, embeds = batch["tokens"], batch.get("embeds")
+        n = tokens.shape[0]
+        glob = [jax.tree_util.tree_leaves(t) for t in state]
+        treedef = jax.tree_util.tree_structure(state.W)
+        host = [[[], [], []] for _ in glob[0]]     # per leaf, tree: clients
+        losses = []
+        for i in range(n):
+            w, m, v = (list(t) for t in glob)
+            step_losses = []
+            for _ in range(self.mix["local_epochs"]):
+                l, g = f["grad"](jax.tree_util.tree_unflatten(treedef, w),
+                                 tokens, embeds, jnp.int32(i))
+                step_losses.append(l)
+                g = jax.tree_util.tree_leaves(g)
+                for j in range(len(w)):
+                    w[j], m[j], v[j] = f["adam"](w[j], m[j], v[j], g[j])
+                    g[j] = None
+            losses.append(f["mean"](step_losses))
+            if self.fault == "unchanged":
+                continue
+            for j in range(len(w)):
+                kept = f["kept"](w[j], glob[0][j], m[j], glob[1][j], v[j],
+                                 glob[2][j])
+                for t, x in enumerate(jax.device_get(kept)):
+                    host[j][t].append(x)
+                w[j] = m[j] = v[j] = None
+        if self.fault == "unchanged":
             return state, losses
-        n = batch["tokens"].shape[0]
-        apply = lambda T, A: _cast(jax.tree.map(
-            lambda t, a: t.astype(F32) + a / n, T, A), T, mode)
-        return State(apply(state.W, aW), apply(state.M, aM),
-                     apply(state.V, aV)), losses
+        new = [[] for _ in glob]
+        for j in range(len(host)):
+            for t in range(3):
+                new[t].append(f["apply"](glob[t][j], np.stack(host[j][t])))
+                host[j][t] = None
+        return State(*(jax.tree_util.tree_unflatten(treedef, leaves)
+                       for leaves in new)), losses
 
-    return jax.jit(one_round)
+    def pieces(self, state, batch) -> list:
+        """``[(name, jitted function, arguments)]``: each distinct
+        compiled call that a round on ``state`` and ``batch`` (arrays or
+        ``jax.ShapeDtypeStruct`` s) makes, with arguments of its shapes."""
+        f, sds = self.fns, jax.ShapeDtypeStruct
+        n = batch["tokens"].shape[0]
+        out = [("grad", f["grad"], (state.W, batch["tokens"],
+                                    batch.get("embeds"),
+                                    sds((), jnp.int32))),
+               ("mean", f["mean"], ([sds((), F32)]
+                                    * self.mix["local_epochs"],))]
+        seen = set()
+        for x in jax.tree_util.tree_leaves(state.W):
+            like = sds(x.shape, x.dtype)
+            if (x.shape, x.dtype) in seen:
+                continue
+            seen.add((x.shape, x.dtype))
+            out.append(("adam", f["adam"],
+                        (like, like, like, sds(x.shape, F32))))
+            if self.fault != "unchanged":
+                out += [("kept", f["kept"], (like,) * 6),
+                        ("apply", f["apply"],
+                         (like, sds((n,) + x.shape, x.dtype)))]
+        return out
 
 
 def leaf_norms(tree):
@@ -402,18 +490,29 @@ def change_norms(W, W0):
         lambda a, b: a.astype(F32) - b.astype(F32), W, W0))
 
 
+def _to_mode(W, mode):
+    """The control's weights: rounded to float8 where the configuration
+    holds bfloat16 (a program of its own, so that the rounding of the
+    initialisation to bfloat16 stays)."""
+    return _cast(_up(W), W, mode)
+
+
+def _zeros(W):
+    return jax.tree.map(jnp.zeros_like, W)
+
+
 def run(c: dict, mix: dict, seed: int, batches, rounds: int,
         mode: str = "f32", fault: Optional[str] = None) -> dict:
     """The reference's readings over the first ``rounds`` rounds: the
     per-client losses of each round, the norm of every leaf of M after
     the first round and of W's change after the last."""
-    key = jax.random.PRNGKey(seed)
-    W0 = jax.jit(lambda k: init_params(c, k))(key)
+    W0 = jax.jit(lambda k: init_params(c, k))(jax.random.PRNGKey(seed))
     if mode == "fp8":
-        W0 = jax.jit(lambda W: _cast(_up(W), W, mode))(W0)
-    zeros = jax.tree.map(jnp.zeros_like, W0)
+        W0 = jax.jit(functools.partial(_to_mode, mode=mode))(W0)
+    zeros = jax.jit(_zeros)(W0)
     state = State(W0, zeros, zeros)
-    step = make_round(c, mix, mode, fault)
+    W0 = jax.device_get(W0)             # on the host until the end
+    step = Round(c, mix, mode, fault)
     losses, m1 = [], None
     for r in range(rounds):
         state, l = step(state, batches[r])
@@ -424,3 +523,25 @@ def run(c: dict, mix: dict, seed: int, batches, rounds: int,
     return {"losses": [list(map(float, l)) for l in losses],
             "m1_norms": [float(x) for x in m1],
             "dw_norms": [float(x) for x in dw]}
+
+
+def pieces(c: dict, mix: dict, batch: dict, mode: str = "f32",
+           fault: Optional[str] = None) -> list:
+    """``[(name, jitted function, arguments)]``: each distinct compiled
+    call of :func:`run` on batches shaped like ``batch`` (arrays or
+    ``jax.ShapeDtypeStruct`` s), with ``jax.ShapeDtypeStruct`` arguments;
+    what compiling each for the chip plans is what the reference needs
+    there (``rehearse.py --reference``)."""
+    sds = jax.ShapeDtypeStruct
+    init = jax.jit(lambda k: init_params(c, k))
+    key = jax.random.PRNGKey(0)
+    W = jax.eval_shape(init, key)
+    state = State(W, W, W)
+    out = [("init", init, (sds(key.shape, key.dtype),))]
+    if mode == "fp8":
+        out.append(("to_mode", jax.jit(functools.partial(_to_mode,
+                                                         mode=mode)), (W,)))
+    return (out + [("zeros", jax.jit(_zeros), (W,))]
+            + Round(c, mix, mode, fault).pieces(state, batch)
+            + [("leaf_norms", jax.jit(leaf_norms), (W,)),
+               ("change_norms", jax.jit(change_norms), (W, W))])

@@ -3,14 +3,20 @@ attached, and print what the compiler plans for it.
 
     JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py \\
         --workload whisper-base.ssm-bisect.c4-l2 [--layers 2]
+    JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py \\
+        --config CONFIG.json --traffic MIX.json [--reference f32]
 
-It builds the round as ``program.Program`` does, from shapes alone, and
-prints ``memory_analysis()`` (argument, output, temporary and total bytes
-on the chip) and the number of Pallas kernels (``tpu_custom_call``) in
-the compiled round.  ``--layers`` overrides the configuration's depth:
-this is how the depth of a configuration cut to one chip is chosen.
-``--reference f32|fp8`` compiles the reference's round (or the control's)
-instead, which must fit the chip too.
+A cell is named by ``--workload``, or, before it has one, by a
+configuration file and a traffic mix file.  It builds the round as
+``program.Program`` does, from shapes alone, and prints
+``memory_analysis()`` (argument, output, temporary and total bytes on
+the chip) and the number of Pallas kernels (``tpu_custom_call``) in the
+compiled round.  ``--layers`` overrides the configuration's depth: this
+is how the depth of a configuration cut to one chip is chosen.
+``--reference f32|fp8`` compiles the reference (or the control) instead,
+which must fit the chip too: each compiled piece of it
+(``reference.pieces``), with its argument and temporary bytes, and the
+largest argument + temporary of them.
 Nothing runs, so it gives no time.
 """
 from __future__ import annotations
@@ -34,6 +40,24 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 import run as bench  # noqa: E402
 
 
+def _described(topology: str):
+    """``place(tree)``: the tree's shapes on one described chip."""
+    dev = topologies.get_topology_desc(platform="tpu",
+                                       topology_name=topology).devices[0]
+    shard = SingleDeviceSharding(dev)
+    return lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=shard), t)
+
+
+def _batch(c: dict, mix: dict) -> dict:
+    C, B, S = mix["clients"], mix["batch"], mix["seq"]
+    batch = {"tokens": jax.ShapeDtypeStruct((C, B, S), jnp.int32)}
+    if c["encoder_frames"]:
+        batch["embeds"] = jax.ShapeDtypeStruct(
+            (C, B, c["encoder_frames"], c["hidden_size"]), jnp.float32)
+    return batch
+
+
 def rehearse(cell: dict, layers=None, topology: str = "v5e:2x2") -> dict:
     import program
     from repro.core import fed as fed_mod
@@ -44,19 +68,10 @@ def rehearse(cell: dict, layers=None, topology: str = "v5e:2x2") -> dict:
         c["num_hidden_layers"] = layers
     cfg = program.arch_config(c)
     fed = program.fed_config(mix)
-    dev = topologies.get_topology_desc(platform="tpu",
-                                       topology_name=topology).devices[0]
-    shard = SingleDeviceSharding(dev)
-    place = lambda t: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=shard), t)
+    place = _described(topology)
     state = place(jax.eval_shape(lambda: fed_mod.fed_init(
         fed, init_params(cfg, jax.random.PRNGKey(0)))))
-    C, B, S = mix["clients"], mix["batch"], mix["seq"]
-    batch = {"tokens": jax.ShapeDtypeStruct((C, B, S), jnp.int32)}
-    if c["encoder_frames"]:
-        batch["embeds"] = jax.ShapeDtypeStruct(
-            (C, B, c["encoder_frames"], c["hidden_size"]), jnp.float32)
-    batch = place(batch)
+    batch = place(_batch(c, mix))
 
     def loss(p, b):
         return loss_fn(cfg, p, b["tokens"], frontend_embeds=b.get("embeds"),
@@ -81,46 +96,47 @@ def rehearse(cell: dict, layers=None, topology: str = "v5e:2x2") -> dict:
     return out
 
 
-def rehearse_reference(cell: dict, mode: str = "f32",
+def rehearse_reference(cell: dict, mode: str = "f32", layers=None,
                        topology: str = "v5e:2x2") -> dict:
-    """The same for the reference's round (``mode="fp8"``: the control),
-    which runs on the chip after the window."""
+    """The same for each compiled piece of the reference
+    (``mode="fp8"``: the control), which runs on the chip after the
+    window, and the largest argument + temporary bytes of them."""
     import reference
 
-    c, mix = cell["c"], cell["mix"]
-    dev = topologies.get_topology_desc(platform="tpu",
-                                       topology_name=topology).devices[0]
-    shard = SingleDeviceSharding(dev)
-    place = lambda t: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=shard), t)
-    W = jax.eval_shape(lambda: reference.init_params(
-        c, jax.random.PRNGKey(0)))
-    state = place(reference.State(W, W, W))
-    C, B, S = mix["clients"], mix["batch"], mix["seq"]
-    batch = {"tokens": jax.ShapeDtypeStruct((C, B, S), jnp.int32)}
-    if c["encoder_frames"]:
-        batch["embeds"] = jax.ShapeDtypeStruct(
-            (C, B, c["encoder_frames"], c["hidden_size"]), jnp.float32)
-    t0 = time.perf_counter()
-    compiled = reference.make_round(c, mix, mode).lower(
-        state, place(batch)).compile()
-    mem = compiled.memory_analysis()
-    return {"mode": mode, "temp_size_in_bytes": int(mem.temp_size_in_bytes),
-            "argument_size_in_bytes": int(mem.argument_size_in_bytes),
-            "compile_s": time.perf_counter() - t0}
+    c, mix = dict(cell["c"]), cell["mix"]
+    if layers is not None:
+        c["num_hidden_layers"] = layers
+    place = _described(topology)
+    pieces = []
+    for name, fn, args in reference.pieces(c, mix, _batch(c, mix), mode):
+        t0 = time.perf_counter()
+        mem = fn.lower(*place(args)).compile().memory_analysis()
+        arg, temp = (int(mem.argument_size_in_bytes),
+                     int(mem.temp_size_in_bytes))
+        shapes = [list(x.shape) for x in jax.tree.leaves(args)][:2]
+        pieces.append({"piece": name, "shapes": shapes,
+                       "argument_size_in_bytes": arg,
+                       "temp_size_in_bytes": temp,
+                       "output_size_in_bytes": int(
+                           mem.output_size_in_bytes),
+                       "argument_plus_temp": arg + temp,
+                       "compile_s": time.perf_counter() - t0})
+    largest = max(pieces, key=lambda p: p["argument_plus_temp"])
+    return {"mode": mode, "layers": c["num_hidden_layers"],
+            "pieces": pieces, "largest": largest}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
+    bench.add_cell_args(ap)
     ap.add_argument("--layers", type=int, default=None)
     ap.add_argument("--reference", choices=("f32", "fp8"), default=None,
-                    help="compile the reference's round (fp8: the "
-                         "control) in place of the program's")
+                    help="compile the reference's pieces (fp8: the "
+                         "control) in place of the program's round")
     args = ap.parse_args(argv)
+    cell = bench.cell_from_args(ap, args)
     jax.config.update("jax_enable_compilation_cache", False)
-    cell = bench.load_cell(args.workload)
-    print(json.dumps(rehearse_reference(cell, args.reference)
+    print(json.dumps(rehearse_reference(cell, args.reference, args.layers)
                      if args.reference else rehearse(cell, args.layers)))
     return 0
 

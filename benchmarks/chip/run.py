@@ -43,6 +43,7 @@ sys.path[:0] = [str(HERE), str(ROOT / "src")]
 import check  # noqa: E402
 import counters  # noqa: E402
 import reference  # noqa: E402
+import scopes  # noqa: E402
 import trace as tr  # noqa: E402
 import traffic  # noqa: E402
 
@@ -77,6 +78,29 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
         "end_to_end": [m["name"] for m in end_to_end],
         "per_layer": [m["name"] for m in per_layer],
     }
+
+
+def add_cell_args(ap) -> None:
+    """``--workload NAME``, or ``--config FILE --traffic FILE`` for a
+    configuration and a traffic mix that no cell names yet."""
+    name = ap.add_mutually_exclusive_group(required=True)
+    name.add_argument("--workload", help="a cell of BENCHMARK.json")
+    name.add_argument("--config", help="a configuration file (with "
+                      "--traffic) that no cell names yet")
+    ap.add_argument("--traffic", help="the traffic mix file of --config")
+
+
+def cell_from_args(ap, args) -> dict:
+    """The cell that :func:`add_cell_args`' arguments name; one from files
+    runs on one chip and has no limits."""
+    if args.workload:
+        return load_cell(args.workload)
+    if not args.traffic:
+        ap.error("--config needs --traffic")
+    c = json.loads(Path(args.config).read_text())
+    return {"name": f"{c['name']}.{Path(args.traffic).stem}", "chips": 1,
+            "c": c, "mix": json.loads(Path(args.traffic).read_text()),
+            "limits": {}, "end_to_end": [], "per_layer": []}
 
 
 def devices(chips: int, require_chip: bool = True):
@@ -177,16 +201,25 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
     dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
            "count": len(devs), "memory_peak_bytes": peak}
     if traced:
-        red = reduce_trace(tdir, prog.hlo_text())
+        trace = load_trace(tdir)
         shutil.rmtree(tdir, ignore_errors=True)
+        hlo = prog.hlo_text()
+        red = tr.reduce(trace, tr.hlo_sources(hlo), tr.layer_tables())
+        staged = scopes.reduce(trace, *scopes.hlo_scopes(hlo))
         log(f"device seconds by layer over {rounds} round(s), "
             f"unattributed included: {red['layer_s']}; busy "
             f"{red['busy_s']!r} of {red['window_s']!r} s")
+        log(f"device seconds by stage: {staged['scope_s']}; unscoped "
+            f"{staged['unscoped_s']!r} s, inherited "
+            f"{staged['inherited_s']!r} s")
         ctx = dict(red, rounds=rounds, chips=cell["chips"],
                    peak=counters.peaks(devs[0].device_kind),
                    model_flops_round=counters.round_model_flops(c, mix),
                    codec_least_bytes_round=counters.codec_least_bytes(
-                       c, mix, uplink))
+                       c, mix, uplink),
+                   scope_s=staged["scope_s"],
+                   counts=scopes.summed(scopes.client_counts(
+                       jax.device_get(mets))))
         values = {m: _reader(m)(ctx) for m in cell["per_layer"]}
         metrics = {m: v for m, v in values.items() if v is not None}
         dev.update(busy_s=red["busy_s"], window_s=red["window_s"])
@@ -221,12 +254,11 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
     return result
 
 
-def reduce_trace(tdir: str, hlo_text: str) -> dict:
+def load_trace(tdir: str) -> dict:
     paths = sorted(Path(tdir).rglob("*.xplane.pb"))
     if not paths:
         raise RuntimeError(f"the profiler wrote no trace under {tdir}")
-    return tr.reduce(tr.load(paths[-1]), tr.hlo_sources(hlo_text),
-                     tr.layer_tables())
+    return tr.load(paths[-1])
 
 
 def main(argv=None) -> int:

@@ -238,10 +238,7 @@ def measure(cell: dict, seed: int, seconds: float, record=None,
     traced_s, rounds, mets = _window(prog, batches, n_check + plain_rounds,
                                      seconds, True, tdir)
     counts.append(client_counts(jax.device_get(mets)))
-    paths = sorted(Path(tdir).rglob("*.xplane.pb"))
-    if not paths:
-        raise RuntimeError(f"the profiler wrote no trace under {tdir}")
-    trace = tr.load(paths[-1])
+    trace = bench.load_trace(tdir)
     shutil.rmtree(tdir, ignore_errors=True)
     hlo = prog.hlo_text()
     scopes, inherited = hlo_scopes(hlo)

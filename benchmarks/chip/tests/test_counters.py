@@ -12,11 +12,16 @@ from conftest import HERE, TINY
 
 
 def config(name):
-    return json.loads((HERE.parent / "configs" / f"{name}.json").read_text())
+    """A configuration of the benchmark, or one kept as test data
+    (``data/``): starcoder2-3b at 2 layers has no cell yet."""
+    path = HERE / "data" / f"{name}.json"
+    if not path.exists():
+        path = HERE.parent / "configs" / f"{name}.json"
+    return json.loads(path.read_text())
 
 
 def test_starcoder2_forward_flops_per_token():
-    c = config("starcoder2-3b")
+    c = config("starcoder2-3b-l2")
     seq = 4096
     d, f, q, kv, vocab = 3072, 12288, 24 * 128, 2 * 128, 49152
     per_token_layer = (2 * d * q            # wq
@@ -47,7 +52,7 @@ def test_whisper_base_forward_flops():
 
 
 @pytest.mark.parametrize("name,params", [("whisper-base", 70_664_192),
-                                         ("starcoder2-3b", 342_899_712)])
+                                         ("starcoder2-3b-l2", 342_899_712)])
 def test_leaf_sizes_count_the_parameters(name, params):
     assert sum(n for n, _ in K.leaf_sizes(config(name))) == params
 

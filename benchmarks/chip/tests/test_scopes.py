@@ -161,3 +161,59 @@ def test_recorded_counters_hold_their_bounds():
                                        for k in scopes.COUNT_KEYS)):
             assert 0 < shipped <= min(sel, cap)
             assert cap == 3745384            # counters.payload_bytes' slots
+
+
+READERS = ("wire_encode_s", "wire_decode_s", "select_s", "server_step_s",
+           "value_fill_share", "mask_dropped_share")
+
+
+def reader(name):
+    import importlib.util
+    from conftest import HERE
+    spec = importlib.util.spec_from_file_location(
+        name, HERE.parent / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_readers_give_the_recorded_stage_metrics(name):
+    """Each stage metric's reader, on the context ``run.py`` builds from
+    the recorded round, gives the number ``scopes.py`` printed."""
+    data = recorded()
+    printed = data["printed"]
+    red = scopes.reduce(data["trace"], data["scopes"],
+                        set(data.get("inherited", ())))
+    ctx = {"scope_s": red["scope_s"], "rounds": printed["rounds"],
+           "counts": scopes.summed(printed["counts"][-1])}
+    got = reader(name)(ctx)
+    assert got is not None
+    assert got == pytest.approx(printed["metrics"][name])
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_readers_find_nothing_in_a_program_without_stages(name):
+    red = scopes.reduce(hand_made(), *scopes.hlo_scopes(
+        HLO.replace("fl.", "")))
+    assert reader(name)({"scope_s": red["scope_s"], "rounds": 1,
+                         "counts": None}) is None
+
+
+def test_a_traced_run_hands_the_counters_to_the_readers(monkeypatch):
+    """A traced run of the harness, without its look for a chip: the
+    last round's counters reach the share readers; the CPU's trace holds
+    no device op, so the stage times find nothing and are left out."""
+    import counters
+    import run as bench
+    from conftest import TINY, TINY_MIX
+    monkeypatch.setattr(counters, "peaks", lambda kind: {
+        "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11})
+    cell = dict(name="tiny", chips=1, c=dict(TINY), mix=dict(TINY_MIX),
+                limits={"loss0_gap": 2e-3}, end_to_end=[],
+                per_layer=list(READERS))
+    res = bench.run(cell, 2**31 + 9, 0.2, True, require_chip=False)
+    got = res["metrics"]
+    assert set(got) == {"value_fill_share", "mask_dropped_share"}
+    assert 0 < got["value_fill_share"]["value"] <= 100
+    assert 0 <= got["mask_dropped_share"]["value"] < 100
